@@ -188,8 +188,9 @@ def trimmed_topk(x: jax.Array, k: int, eps: float = 0.2) -> Selected:
 
     Survivor restriction is expressed by zeroing the score of trimmed
     elements; on TPU the survivor set is first compacted into a small buffer
-    by the Pallas block-bucketed compaction kernel (kernels/compact.py), which
-    is where the paper's speedup comes from. The selected set is identical.
+    by the Pallas block-bucketed compaction kernel (kernels/segmented.py),
+    which is where the paper's speedup comes from. The selected set is
+    identical.
     """
     ax = jnp.abs(x)
     mean, mx = _stats(ax)

@@ -83,11 +83,17 @@ def sparse_allgather(msg: jax.Array, axes: tuple[str, ...]) -> jax.Array:
 
     Returns [num_workers, msg_len] with num_workers = prod(axis sizes).
     Empty ``axes`` (single-worker smoke paths) is the identity.
+
+    The message travels as its int32 bits. XLA:TPU lowers this all-gather
+    to an all-reduce (sum) of a zero buffer holding each worker's message
+    in its own slot; on the f32 view that sum flushes the bitcast-int32
+    counts and indices (denormals) to zero and drops every message. An
+    integer sum with zeros moves the bits exactly.
     """
     if not axes:
         return msg[None]
     name = axes if len(axes) > 1 else axes[0]
-    out = jax.lax.all_gather(msg, name)
+    out = _i2f(jax.lax.all_gather(_f2i(msg), name))
     return out.reshape(-1, msg.shape[0])
 
 
@@ -128,7 +134,7 @@ def hierarchical_allgather(msg: jax.Array, inter_axes: tuple[str, ...],
     zeros), so integer addition makes the reassembly an exact bit move.
     An f32 psum would corrupt the message — the wire format embeds
     bitcast-int32 counts/indices whose f32 views are denormals, and
-    backends running flush-to-zero (XLA:CPU reductions do) would zero
+    backends running flush-to-zero (XLA:CPU reductions and TPU do) would zero
     them. Downstream decompression therefore sees byte-identical input to
     a flat ``sparse_allgather`` over the FULL axis tuple: rows come out
     inter-major, and when ``sync_axes`` names an order with the intra
@@ -140,9 +146,8 @@ def hierarchical_allgather(msg: jax.Array, inter_axes: tuple[str, ...],
         return sparse_allgather(msg, inter_axes)
     if not inter_axes:
         return sparse_allgather(msg, (intra_axis,))
-    from repro.jaxcompat import axis_size
     g_inter = sparse_allgather(msg, inter_axes)        # [n_inter, len]
-    n_local = axis_size(intra_axis)
+    n_local = jax.lax.axis_size(intra_axis)
     my_rank = jax.lax.axis_index(intra_axis)
     full = jnp.zeros((g_inter.shape[0], n_local, g_inter.shape[1]),
                      jnp.int32)
@@ -151,7 +156,7 @@ def hierarchical_allgather(msg: jax.Array, inter_axes: tuple[str, ...],
     full = jax.lax.psum(full, intra_axis)
     out = _i2f(full)                                   # [n_inter, n_local, L]
     if sync_axes and tuple(sync_axes) != tuple(inter_axes) + (intra_axis,):
-        sizes = [axis_size(a) for a in inter_axes]
+        sizes = [jax.lax.axis_size(a) for a in inter_axes]
         out = out.reshape(*sizes, n_local, out.shape[-1])
         out = jnp.moveaxis(out, len(sizes), sync_axes.index(intra_axis))
     return out.reshape(-1, msg.shape[0])

@@ -91,10 +91,9 @@ class _Base:
         self.timer = timer if timer is not None else NullTimer()
 
     def num_workers(self) -> int:
-        from repro.jaxcompat import axis_size
         n = 1
         for ax in self.sync_axes:
-            n *= axis_size(ax)
+            n *= jax.lax.axis_size(ax)
         return n
 
     def pack(self, sel: Selected, quantized: bool) -> jax.Array:

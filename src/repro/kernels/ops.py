@@ -3,42 +3,35 @@
 These mirror the pure-jnp selectors in core/selection.py (same Selected
 contract) but route the hot loops through the TPU kernels:
 
-    trimmed_topk           = block_stats -> ratio loop(count_gt)
+    trimmed_topk           = abs_sum_max -> ratio loop(count_gt)
                              -> compact_gt -> exact top-k on the short bucket
-    threshold_binary_search = block_stats -> bisect loop(count_gt)
+    threshold_binary_search = abs_sum_max -> bisect loop(count_gt)
                              -> compact_gt -> first-2k filter
 
-``interpret`` defaults to None = backend auto-detection: compiled kernels
-on a TPU backend (the BlockSpec tiling is the lowering target),
-interpreter mode everywhere else (CPU tests, debugging). Pass an explicit
-bool to override either way. The auto default is what
-``compressor_params["backend"] = "pallas"`` threads through the
+A lone leaf is a one-segment arena: the per-leaf kernels below are the
+segmented kernels of ``kernels.segmented`` run over a single slot, so
+the per-leaf and flat-arena pipelines share one kernel family.
+
+``interpret`` defaults to None, resolved by
+``segmented.resolve_interpret``: compiled kernels on a TPU backend,
+interpreter mode on CPU (tests), an error anywhere else. The default is
+what ``compressor_params["backend"] = "pallas"`` threads through the
 compressor registry, so a TrainConfig needs no extra knob per platform.
 """
 from __future__ import annotations
 
-import functools
-import math
-
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.selection import (Selected, ladder_ratio, mean_of_sum,
                                   search_band, threshold_at)
 
-from .block_stats import abs_sum_max
-from .compact import compact_gt
-from .residual_update import residual_update as _residual_update_kernel
-from .threshold_count import count_gt
+from .segmented import (_cap_for, _gather_topk_from_buckets,
+                        seg_abs_sum_max, seg_compact_gt, seg_count_gt,
+                        seg_residual_update_stats)
 
 DEFAULT_BLOCK = 1024
-
-
-def resolve_interpret(interpret: bool | None) -> bool:
-    """``None`` -> interpret unless running on a real TPU backend."""
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
 
 
 def _to2d(x: jax.Array, block: int) -> tuple[jax.Array, int]:
@@ -48,12 +41,8 @@ def _to2d(x: jax.Array, block: int) -> tuple[jax.Array, int]:
     return xp.reshape(nb, block), n
 
 
-def _cap_for(capacity: int, nb: int, block: int) -> int:
-    """Per-block bucket size for gathering ``capacity`` survivors: 4x the
-    uniform per-block share, rounded to the 8-sublane granule, clamped to
-    the block."""
-    per = -(-capacity // nb)
-    return min(block, max(8, ((4 * per + 7) // 8) * 8))
+def _one_slot(nb: int) -> np.ndarray:
+    return np.zeros(nb, np.int32)
 
 
 def _bucket_cap(k: int, nb: int, block: int) -> int:
@@ -61,43 +50,44 @@ def _bucket_cap(k: int, nb: int, block: int) -> int:
     return _cap_for(2 * k, nb, block)
 
 
-def stats(x: jax.Array, *, block: int = DEFAULT_BLOCK,
-          interpret: bool | None = None) -> tuple[jax.Array, jax.Array]:
-    """(mean(|x|), max(|x|)) via the fused reduction kernel."""
-    interpret = resolve_interpret(interpret)
-    x2d, n = _to2d(x, block)
-    s, m = abs_sum_max(x2d, interpret=interpret)
-    return mean_of_sum(s, n), m
+def abs_sum_max(x2d: jax.Array, *, interpret: bool | None = None
+                ) -> tuple[jax.Array, jax.Array]:
+    """(sum|x|, max|x|) of a zero-padded [nb, block] leaf (Alg 2/3
+    statistics; mean = sum / n is formed by the caller so padding
+    contributes nothing)."""
+    s, m = seg_abs_sum_max(x2d, _one_slot(x2d.shape[0]), 1,
+                           interpret=interpret)
+    return s[0], m[0]
 
 
-def nnz_gt(x: jax.Array, threshold: jax.Array, *, block: int = DEFAULT_BLOCK,
-           interpret: bool | None = None) -> jax.Array:
-    x2d, _ = _to2d(x, block)
-    interpret = resolve_interpret(interpret)
-    return count_gt(x2d, threshold, interpret=interpret)
+def count_gt(x2d: jax.Array, threshold: jax.Array, *,
+             interpret: bool | None = None) -> jax.Array:
+    """nnz(|x| > t) as i32 — the count_nonzero loop of Alg 3. The
+    threshold is an operand, so one compiled kernel serves every search
+    iteration; t >= 0 drops the zero padding automatically."""
+    thr = jnp.reshape(jnp.asarray(threshold, jnp.float32), (1,))
+    return seg_count_gt(x2d, _one_slot(x2d.shape[0]), thr,
+                        interpret=interpret)[0]
 
 
-def _gather_topk_from_buckets(vals, idx, k: int, total: int,
-                              order_by_magnitude: bool):
-    """Pick k entries from the [nb, cap] buckets: by |value| (trimmed top-k)
-    or simply the first-k valid slots (binary-search filter)."""
-    fv, fi = vals.reshape(-1), idx.reshape(-1)
-    valid = fi < total
-    if order_by_magnitude:
-        score = jnp.where(valid, jnp.abs(fv), -1.0)
-    else:
-        score = valid.astype(jnp.float32)
-    _, pos = jax.lax.top_k(score, k)
-    sel_idx = jnp.where(valid[pos], fi[pos], total)
-    sel_val = jnp.where(valid[pos], fv[pos], 0.0)
-    return sel_idx.astype(jnp.int32), sel_val
+def compact_gt(x2d: jax.Array, threshold: jax.Array, cap_per_block: int,
+               total: int, *, interpret: bool | None = None
+               ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Block-bucketed compaction of a zero-padded [nb, block] leaf.
+    Returns (values [nb, cap], indices [nb, cap] i32 — padding ==
+    total, counts [nb])."""
+    nb, block = x2d.shape
+    thr = jnp.reshape(jnp.asarray(threshold, jnp.float32), (1,))
+    return seg_compact_gt(x2d, _one_slot(nb),
+                          np.arange(nb, dtype=np.int32) * block,
+                          np.full(nb, total, np.int32), thr, cap_per_block,
+                          interpret=interpret)
 
 
 def trimmed_topk(x: jax.Array, k: int, *, eps: float = 0.2,
                  block: int = DEFAULT_BLOCK,
                  interpret: bool | None = None) -> Selected:
     """Algorithm 2 on the TPU kernels. capacity == k."""
-    interpret = resolve_interpret(interpret)
     x2d, n = _to2d(x, block)
     nb = x2d.shape[0]
     s, mx = abs_sum_max(x2d, interpret=interpret)
@@ -116,18 +106,18 @@ def trimmed_topk(x: jax.Array, k: int, *, eps: float = 0.2,
     step0 = jnp.int32(1)
     nnz0 = count_gt(x2d, threshold_at(mean, mx, ladder_ratio(step0, eps)),
                     interpret=interpret)
-    step, _ = jax.lax.while_loop(cond, body, (step0, nnz0))
+    step, nnz = jax.lax.while_loop(cond, body, (step0, nnz0))
     thr = threshold_at(mean, mx, ladder_ratio(step, eps))
 
     cap = _bucket_cap(k, nb, block)
     vals, idx, counts = compact_gt(x2d, thr, cap, n, interpret=interpret)
     si, sv = _gather_topk_from_buckets(vals, idx, k, n,
                                        order_by_magnitude=True)
-    # Alg 2's coarse (eps=0.2) threshold steps can leave far more than k
-    # survivors; if any block overflowed its bucket, elements above the
-    # threshold were dropped and the bucket top-k may be wrong — fall back
-    # to the exact selector for this (rare) iteration.
-    overflow = jnp.any(counts > cap)
+    # The buckets cannot give the top-k when Alg 2's coarse (eps=0.2)
+    # ladder bottoms out with fewer than k survivors, or leaves so many
+    # that a block overflows its bucket and drops some: take the exact
+    # selector for this (rare) iteration.
+    fallback = (nnz < k) | jnp.any(counts > cap)
 
     def from_buckets(_):
         return si, sv
@@ -137,7 +127,7 @@ def trimmed_topk(x: jax.Array, k: int, *, eps: float = 0.2,
         s = exact_topk(x.reshape(-1).astype(jnp.float32), k)
         return s.indices, s.values
 
-    si, sv = jax.lax.cond(overflow, exact, from_buckets, operand=None)
+    si, sv = jax.lax.cond(fallback, exact, from_buckets, operand=None)
     return Selected(si, sv, jnp.int32(k))
 
 
@@ -151,7 +141,6 @@ def threshold_binary_search(x: jax.Array, k: int, *, eps: float = 1e-3,
     ``warm`` seeds the bisection bracket from the previous converged
     threshold (``selection.search_band``); ``None`` is the cold search.
     """
-    interpret = resolve_interpret(interpret)
     x2d, n = _to2d(x, block)
     s, mx = abs_sum_max(x2d, interpret=interpret)
     mean = mean_of_sum(s, n)
@@ -171,7 +160,6 @@ def threshold_filter(x: jax.Array, threshold: jax.Array, capacity: int, *,
     compressor on the pallas backend, so threshold *reuse* steps skip the
     search kernels entirely instead of re-searching.
     """
-    interpret = resolve_interpret(interpret)
     x2d, n = _to2d(x, block)
     return _filter_2d(x, x2d, n, threshold, capacity, block,
                       interpret=interpret)
@@ -210,12 +198,12 @@ def residual_update(grad: jax.Array, u: jax.Array, v: jax.Array, *,
                     interpret: bool | None = None
                     ) -> tuple[jax.Array, jax.Array]:
     """Fused U/V update on arbitrary-shaped leaves."""
-    interpret = resolve_interpret(interpret)
     shape, n = grad.shape, grad.size
     g2, _ = _to2d(grad, block)
     u2, _ = _to2d(u, block)
     v2, _ = _to2d(v, block)
-    u_new, v_new = _residual_update_kernel(
-        g2, u2, v2, momentum=momentum, nesterov=nesterov, interpret=interpret)
+    v_new, u_new, _, _ = seg_residual_update_stats(
+        g2, v2, u2, None, _one_slot(g2.shape[0]), 1, momentum=momentum,
+        nesterov=nesterov, interpret=interpret)
     return (u_new.reshape(-1)[:n].reshape(shape),
             v_new.reshape(-1)[:n].reshape(shape))

@@ -183,7 +183,7 @@ def seg_residual_update_stats(
     g = g2d.astype(jnp.float32)
     if weight_decay:
         g = g + weight_decay * p2d.astype(jnp.float32)
-    if momentum:
+    if u2d is not None:
         u_new, v_new = residual_update(g, u2d, v2d, momentum=momentum,
                                        nesterov=nesterov)
     else:

@@ -22,9 +22,10 @@ identical to running the per-leaf selectors leaf by leaf:
                           HBM round-trip instead of two).
 
 Bitwise parity rests on the arena layout: slots are ``ARENA_BLOCK``-
-aligned and zero-padded, so each slot's rows are exactly the 2-D view
-the per-leaf kernels build, and the sequential grid accumulates each
-segment's blocks in the same ascending order as the per-leaf grid.
+aligned and zero-padded, so each slot's rows are exactly the 2-D view a
+lone leaf has, and each segment's sums add its rows in ascending order
+wherever the slot sits (see "Tiling" below). The per-leaf selectors in
+``ops`` run these same kernels over one segment.
 
 The ``*_segments`` selectors orchestrate the kernels into Algorithm 2/3
 over all slots at once: threshold search loops are vectorized across
@@ -33,7 +34,7 @@ every segment walks the exact iterate sequence its per-leaf loop would.
 ``use_pallas=False`` routes through the pure-jnp twins in ``ref.py`` —
 the same math the per-leaf jnp selectors in ``core.selection`` run.
 
-``interpret`` follows ``ops.resolve_interpret`` (None = auto-detect).
+``interpret`` follows ``resolve_interpret`` (None = by backend).
 """
 from __future__ import annotations
 
@@ -44,12 +45,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.selection import (Selected, bisect_midpoint, ladder_ratio,
                                   threshold_at, threshold_filter, warm_ratio)
 
 from . import ref
-from .ops import _cap_for, _gather_topk_from_buckets, resolve_interpret
 
 __all__ = [
     "seg_abs_sum_max", "seg_count_gt", "seg_compact_gt",
@@ -62,52 +63,135 @@ __all__ = [
 # ---------------------------------------------------------------------------
 # Pallas kernels
 # ---------------------------------------------------------------------------
+#
+# Tiling. Each kernel walks the [nb, block] arena ``_rows(nb)`` rows at a
+# time: ROW_BLOCK rows (a multiple of 16, so f32 and bf16 blocks are
+# legal Mosaic tiles), or the whole arena when it is shorter. Per-segment
+# inputs and accumulators are (1, n_seg) vectors, so nothing is read or
+# written as a VMEM scalar and no per-row metadata array is streamed in.
+#
+# A row block may straddle slot boundaries (slots are aligned to one row,
+# not to a row block). Each kernel recovers the owning segment of every
+# row from the per-segment row bounds [lo, hi): rows past ``nb`` in the
+# last, partial block belong to no segment and contribute nothing.
+# Per-segment sums add one row sum at a time in ascending row order — the
+# same chain whichever rows share a block — so a slot's statistics do not
+# depend on where it sits in the arena, and the per-leaf, per-arena and
+# stacked-arena launches agree bitwise.
 
-def _lane(n_seg: int) -> jax.Array:
-    return jax.lax.broadcasted_iota(jnp.int32, (1, n_seg), 1)
+ROW_BLOCK = 64
 
 
-def _pick(vec_ref, seg: jax.Array, n_seg: int) -> jax.Array:
-    """One-hot pick of a (1, n_seg) block's ``seg`` entry (TPU-safe —
-    no dynamic VMEM scalar indexing)."""
-    return jnp.sum(jnp.where(_lane(n_seg) == seg, vec_ref[...], 0.0))
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``None`` -> compiled on TPU, interpreted on CPU (tests). The
+    kernels have no lowering for any other backend, so that raises
+    instead of silently interpreting."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas selection kernels target TPU (interpreted on "
+            f"CPU); backend {backend!r} has neither — use backend='jnp'")
+    return backend == "cpu"
 
 
-def _stats_kernel(seg_ref, x_ref, sum_ref, max_ref, *, n_seg: int):
-    i = pl.program_id(0)
+def _rows(nb: int) -> int:
+    return nb if nb <= ROW_BLOCK else ROW_BLOCK
 
-    @pl.when(i == 0)
+
+def _bounds(block_seg, n_seg: int) -> tuple[np.ndarray, np.ndarray]:
+    """Per-segment [lo, hi) row bounds of an ascending ``block_seg``."""
+    bs = np.asarray(block_seg)
+    if np.any(np.diff(bs) < 0):
+        raise ValueError("block_seg must be ascending (contiguous slots)")
+    ids = np.arange(n_seg)
+    return (np.searchsorted(bs, ids, "left").astype(np.int32),
+            np.searchsorted(bs, ids, "right").astype(np.int32))
+
+
+def _seg_strides(stride_b, lo: np.ndarray) -> np.ndarray:
+    st = np.asarray(stride_b, np.int32)[lo]
+    if np.any(st < 1) or np.any(st & (st - 1)):
+        raise ValueError(f"sampling strides must be powers of two: {st}")
+    return st
+
+
+def _vec(a, dtype=jnp.int32) -> jax.Array:
+    a = jnp.asarray(a, dtype)
+    return a.reshape(1, a.size)
+
+
+def _vec_spec(n_seg: int) -> pl.BlockSpec:
+    return pl.BlockSpec((1, n_seg), lambda i: (0, 0))
+
+
+def _row_spec(rows: int, width: int) -> pl.BlockSpec:
+    return pl.BlockSpec((rows, width), lambda i: (i, 0))
+
+
+def _row_ids(rows: int) -> jax.Array:
+    return (pl.program_id(0) * rows
+            + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0))
+
+
+def _hit(lo_ref, hi_ref, rows: int) -> jax.Array:
+    """(rows, n_seg): row r of this block belongs to segment s."""
+    g = _row_ids(rows)
+    return (lo_ref[...] <= g) & (g < hi_ref[...])
+
+
+def _per_row(hit: jax.Array, vec_ref) -> jax.Array:
+    """Each row's entry of a per-segment vector, as a (rows, 1) column
+    (exact: one nonzero term; 0 for rows of no segment)."""
+    v = vec_ref[...]
+    return jnp.sum(jnp.where(hit, v, jnp.zeros((), v.dtype)), axis=1,
+                   keepdims=True)
+
+
+def _on_stride(hit: jax.Array, stride_ref, shape) -> jax.Array:
+    """Columns on each row's power-of-two sampling grid (strides divide
+    the block, so these are the slot-local ``[::stride]`` elements)."""
+    st = jnp.maximum(_per_row(hit, stride_ref), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (col & (st - 1)) == 0
+
+
+def _accumulate_stats(lo_ref, hi_ref, sum_ref, max_ref, ax: jax.Array):
+    """Add this block's per-segment (sum, max) of ``ax`` into the
+    (1, n_seg) accumulators, summing row by row in ascending order."""
+    rows = ax.shape[0]
+
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         sum_ref[...] = jnp.zeros(sum_ref.shape, sum_ref.dtype)
         max_ref[...] = jnp.zeros(max_ref.shape, max_ref.dtype)
 
+    rs = jnp.sum(ax, axis=1, keepdims=True)
+    rm = jnp.max(ax, axis=1, keepdims=True)
+    lo, hi = lo_ref[...], hi_ref[...]
+    g0 = pl.program_id(0) * rows
+    acc = sum_ref[...]
+    for r in range(rows):
+        on = (lo <= g0 + r) & (g0 + r < hi)
+        acc = acc + jnp.where(on, rs[r:r + 1], 0.0)
+    sum_ref[...] = acc
+    hit = _hit(lo_ref, hi_ref, rows)
+    max_ref[...] = jnp.maximum(
+        max_ref[...],
+        jnp.max(jnp.where(hit, rm, 0.0), axis=0, keepdims=True))
+
+
+def _stats_kernel(lo_ref, hi_ref, *refs, strided: bool):
+    if strided:
+        stride_ref, x_ref, sum_ref, max_ref = refs
+    else:
+        x_ref, sum_ref, max_ref = refs
     ax = jnp.abs(x_ref[...].astype(jnp.float32))
-    hit = _lane(n_seg) == seg_ref[0, 0]
-    sum_ref[...] += jnp.where(hit, jnp.sum(ax), 0.0)
-    max_ref[...] = jnp.maximum(max_ref[...],
-                               jnp.where(hit, jnp.max(ax), 0.0))
-
-
-def _stats_kernel_strided(seg_ref, stride_ref, x_ref, sum_ref, max_ref, *,
-                          n_seg: int, block: int):
-    """Strided-subsample stats: only columns on the row's stride grid
-    contribute (strides divide the block, so the masked columns are the
-    slot-local ``[::stride]`` subsample the sampled selector defines)."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        sum_ref[...] = jnp.zeros(sum_ref.shape, sum_ref.dtype)
-        max_ref[...] = jnp.zeros(max_ref.shape, max_ref.dtype)
-
-    ax = jnp.abs(x_ref[...].astype(jnp.float32))
-    inc = (jax.lax.broadcasted_iota(jnp.int32, ax.shape, 1)
-           % stride_ref[0, 0]) == 0
-    axm = jnp.where(inc, ax, 0.0)
-    hit = _lane(n_seg) == seg_ref[0, 0]
-    sum_ref[...] += jnp.where(hit, jnp.sum(axm), 0.0)
-    max_ref[...] = jnp.maximum(max_ref[...],
-                               jnp.where(hit, jnp.max(axm), 0.0))
+    if strided:
+        hit = _hit(lo_ref, hi_ref, ax.shape[0])
+        ax = jnp.where(_on_stride(hit, stride_ref, ax.shape), ax, 0.0)
+    _accumulate_stats(lo_ref, hi_ref, sum_ref, max_ref, ax)
 
 
 def seg_abs_sum_max(x2d: jax.Array, block_seg: np.ndarray, n_seg: int, *,
@@ -116,67 +200,48 @@ def seg_abs_sum_max(x2d: jax.Array, block_seg: np.ndarray, n_seg: int, *,
                     ) -> tuple[jax.Array, jax.Array]:
     """Per-segment (sum|x|, max|x|) over [nb, block] arena rows.
 
-    ``stride_b`` (per-row ints) restricts the statistics to each row's
-    stride grid for the sampled selector; ``None`` keeps the exact-path
-    kernel (and its graph) untouched.
+    ``stride_b`` (per-row power-of-two ints) restricts the statistics to
+    each row's stride grid for the sampled selector; ``None`` keeps the
+    exact-path kernel.
     """
     nb, block = x2d.shape
-    seg = jnp.asarray(block_seg, jnp.int32).reshape(nb, 1)
-    row1 = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    acc = pl.BlockSpec((1, n_seg), lambda i: (0, 0))
-    if stride_b is None:
-        kern = functools.partial(_stats_kernel, n_seg=n_seg)
-        ins = (seg, x2d)
-        in_specs = [row1, pl.BlockSpec((1, block), lambda i: (i, 0))]
-    else:
-        kern = functools.partial(_stats_kernel_strided, n_seg=n_seg,
-                                 block=block)
-        stride = jnp.asarray(np.asarray(stride_b), jnp.int32).reshape(nb, 1)
-        ins = (seg, stride, x2d)
-        in_specs = [row1, row1, pl.BlockSpec((1, block), lambda i: (i, 0))]
+    lo, hi = _bounds(block_seg, n_seg)
+    rows = _rows(nb)
+    vec = _vec_spec(n_seg)
+    ins, in_specs = [_vec(lo), _vec(hi)], [vec, vec]
+    if stride_b is not None:
+        ins.append(_vec(_seg_strides(stride_b, lo)))
+        in_specs.append(vec)
+    ins.append(x2d)
+    in_specs.append(_row_spec(rows, block))
     s, m = pl.pallas_call(
-        kern,
-        grid=(nb,),
+        functools.partial(_stats_kernel, strided=stride_b is not None),
+        grid=(pl.cdiv(nb, rows),),
         in_specs=in_specs,
-        out_specs=[acc, acc],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, n_seg), jnp.float32),
-            jax.ShapeDtypeStruct((1, n_seg), jnp.float32),
-        ],
+        out_specs=[vec, vec],
+        out_shape=[jax.ShapeDtypeStruct((1, n_seg), jnp.float32)] * 2,
         interpret=resolve_interpret(interpret),
     )(*ins)
     return s[0], m[0]
 
 
-def _count_kernel(seg_ref, thr_ref, x_ref, out_ref, *, n_seg: int):
-    i = pl.program_id(0)
+def _count_kernel(lo_ref, hi_ref, *refs, strided: bool):
+    if strided:
+        stride_ref, thr_ref, x_ref, out_ref = refs
+    else:
+        thr_ref, x_ref, out_ref = refs
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
-    seg = seg_ref[0, 0]
-    thr = _pick(thr_ref, seg, n_seg)
-    c = jnp.sum((jnp.abs(x_ref[...].astype(jnp.float32)) > thr)
-                .astype(jnp.int32))
-    out_ref[...] += jnp.where(_lane(n_seg) == seg, c, 0)
-
-
-def _count_kernel_strided(seg_ref, stride_ref, thr_ref, x_ref, out_ref, *,
-                          n_seg: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
-
-    seg = seg_ref[0, 0]
-    thr = _pick(thr_ref, seg, n_seg)
     ax = jnp.abs(x_ref[...].astype(jnp.float32))
-    inc = (jax.lax.broadcasted_iota(jnp.int32, ax.shape, 1)
-           % stride_ref[0, 0]) == 0
-    c = jnp.sum(((ax > thr) & inc).astype(jnp.int32))
-    out_ref[...] += jnp.where(_lane(n_seg) == seg, c, 0)
+    hit = _hit(lo_ref, hi_ref, ax.shape[0])
+    over = ax > _per_row(hit, thr_ref)
+    if strided:
+        over = over & _on_stride(hit, stride_ref, ax.shape)
+    c = jnp.sum(over.astype(jnp.int32), axis=1, keepdims=True)
+    out_ref[...] += jnp.sum(jnp.where(hit, c, 0), axis=0, keepdims=True)
 
 
 def seg_count_gt(x2d: jax.Array, block_seg: np.ndarray,
@@ -185,29 +250,24 @@ def seg_count_gt(x2d: jax.Array, block_seg: np.ndarray,
                  interpret: bool | None = None
                  ) -> jax.Array:
     """Per-segment nnz(|x| > thresholds[seg]) — one launch per search
-    step for the whole arena (the per-leaf path launches one per leaf).
+    step for the whole arena.
 
     ``stride_b`` counts only each row's stride-grid columns (the sampled
     selector's subsample count — integer, so stride-1 rows are exact)."""
     nb, block = x2d.shape
     n_seg = thresholds.shape[0]
-    seg = jnp.asarray(block_seg, jnp.int32).reshape(nb, 1)
-    thr2d = thresholds.astype(jnp.float32).reshape(1, n_seg)
-    row1 = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    vec = pl.BlockSpec((1, n_seg), lambda i: (0, 0))
-    rowb = pl.BlockSpec((1, block), lambda i: (i, 0))
-    if stride_b is None:
-        kern = functools.partial(_count_kernel, n_seg=n_seg)
-        ins = (seg, thr2d, x2d)
-        in_specs = [row1, vec, rowb]
-    else:
-        kern = functools.partial(_count_kernel_strided, n_seg=n_seg)
-        stride = jnp.asarray(np.asarray(stride_b), jnp.int32).reshape(nb, 1)
-        ins = (seg, stride, thr2d, x2d)
-        in_specs = [row1, row1, vec, rowb]
+    lo, hi = _bounds(block_seg, n_seg)
+    rows = _rows(nb)
+    vec = _vec_spec(n_seg)
+    ins, in_specs = [_vec(lo), _vec(hi)], [vec, vec]
+    if stride_b is not None:
+        ins.append(_vec(_seg_strides(stride_b, lo)))
+        in_specs.append(vec)
+    ins += [_vec(thresholds, jnp.float32), x2d]
+    in_specs += [vec, _row_spec(rows, block)]
     out = pl.pallas_call(
-        kern,
-        grid=(nb,),
+        functools.partial(_count_kernel, strided=stride_b is not None),
+        grid=(pl.cdiv(nb, rows),),
         in_specs=in_specs,
         out_specs=vec,
         out_shape=jax.ShapeDtypeStruct((1, n_seg), jnp.int32),
@@ -216,27 +276,58 @@ def seg_count_gt(x2d: jax.Array, block_seg: np.ndarray,
     return out[0]
 
 
-def _compact_kernel(seg_ref, base_ref, size_ref, thr_ref, x_ref,
-                    vals_ref, idx_ref, cnt_ref, *, block: int, cap: int,
-                    n_seg: int):
-    x = x_ref[...].reshape(block).astype(jnp.float32)
-    seg = seg_ref[0, 0]
-    size = size_ref[0, 0]
-    thr = _pick(thr_ref, seg, n_seg)
-    lidx = base_ref[0, 0] + jax.lax.iota(jnp.int32, block)
-    mask = (jnp.abs(x) > thr) & (lidx < size)
+def _lane_cumsum(m: jax.Array) -> jax.Array:
+    """Inclusive prefix sum along lanes (log-step shifted adds; Mosaic
+    has no cumsum lowering)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, m.shape, 1)
+    s = 1
+    while s < m.shape[1]:
+        m = m + jnp.where(lane >= s, pltpu.roll(m, s, 1), 0)
+        s *= 2
+    return m
 
-    cnt_ref[0, 0] = jnp.sum(mask.astype(jnp.int32))
 
-    pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
-    live = mask & (pos < cap)
-    onehot = (pos[:, None] == jax.lax.iota(jnp.int32, cap)[None, :]) \
-        & live[:, None]
-    vals_ref[...] = (x[:, None] * onehot.astype(jnp.float32)) \
-        .sum(0).reshape(1, cap)
-    idx_packed = jnp.where(onehot, lidx[:, None], 0).sum(0)
-    filled = jnp.sum(onehot.astype(jnp.int32), axis=0) > 0
-    idx_ref[...] = jnp.where(filled, idx_packed, size).reshape(1, cap)
+def _compact_kernel(lo_ref, hi_ref, size_ref, thr_ref, x_ref,
+                    vals_ref, idx_ref, cnt_ref, slot_scr, base_scr,
+                    size_scr, *, cap: int):
+    rows, block = x_ref.shape
+    x = x_ref[...].astype(jnp.float32)
+    hit = _hit(lo_ref, hi_ref, rows)
+    base = (_row_ids(rows) - _per_row(hit, lo_ref)) * block
+    size = _per_row(hit, size_ref)
+    lidx = base + jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1)
+    mask = (jnp.abs(x) > _per_row(hit, thr_ref)) & (lidx < size)
+    m = mask.astype(jnp.int32)
+    cnt_ref[...] = jnp.sum(m, axis=1, keepdims=True)
+    # bucket slot of each survivor (0-based); overflow beyond cap -> -1
+    pos = _lane_cumsum(m) - 1
+    slot_scr[...] = jnp.where(mask & (pos < cap), pos, -1)
+    base_scr[...] = base
+    size_scr[...] = size
+
+    # Per row, a [cap, block] one-hot packs survivors into bucket slots
+    # with exact masked lane sums (one nonzero term each), and a [cap,
+    # cap] diagonal turns the resulting column into the output row.
+    slot_ids = jax.lax.broadcasted_iota(jnp.int32, (cap, block), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (cap, cap), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (cap, cap), 1))
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, cap), 1)
+
+    def pack_row(r, carry):
+        at = pl.ds(r, 1)
+        onehot = slot_scr[at, :] == slot_ids
+        xr = x_ref[at, :].astype(jnp.float32)
+        v = jnp.sum(jnp.where(onehot, xr, 0.0), axis=1, keepdims=True)
+        o = jnp.sum(jnp.where(onehot, lane, 0), axis=1, keepdims=True)
+        v = jnp.sum(jnp.where(eye, v, 0.0), axis=0, keepdims=True)
+        o = jnp.sum(jnp.where(eye, o, 0), axis=0, keepdims=True)
+        vals_ref[at, :] = v
+        idx_ref[at, :] = jnp.where(col < cnt_ref[at, :],
+                                   base_scr[at, :] + o, size_scr[at, :])
+        return carry
+
+    jax.lax.fori_loop(0, rows, pack_row, 0)
 
 
 def seg_compact_gt(x2d: jax.Array, block_seg: np.ndarray,
@@ -244,69 +335,65 @@ def seg_compact_gt(x2d: jax.Array, block_seg: np.ndarray,
                    thresholds: jax.Array, cap_per_block: int, *,
                    interpret: bool | None = None
                    ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """``compact_gt`` with per-segment thresholds and SLOT-LOCAL indices.
+    """Block-bucketed compaction with per-segment thresholds and
+    SLOT-LOCAL indices.
 
-    Returns (values [nb, cap], indices [nb, cap] i32 — local to the
-    owning slot, padding == slot size, counts [nb] pre-clamp). Feeding
-    the buckets straight into the per-slot message gather removes the
-    separate per-leaf pack pass.
+    GPU RedSync compacts survivors (|x| > t) with a device-wide prefix
+    sum and scattered writes; here every arena row packs its own
+    survivors to the front of a private ``cap_per_block`` bucket — no
+    cross-row carry. Returns (values [nb, cap], indices [nb, cap] i32 —
+    local to the owning slot, padding == slot size, counts [nb]
+    pre-clamp, so the caller detects bucket overflow). Indices are
+    packed as exact i32 sums: f32 cannot hold indices past 2^24.
+    ``block_base`` must be each row's offset within its slot (the arena
+    layout) and ``block_size`` the owning slot's size.
     """
     nb, block = x2d.shape
     n_seg = thresholds.shape[0]
-    as_col = lambda a: jnp.asarray(a, jnp.int32).reshape(nb, 1)  # noqa: E731
-    kern = functools.partial(_compact_kernel, block=block,
-                             cap=cap_per_block, n_seg=n_seg)
+    lo, hi = _bounds(block_seg, n_seg)
+    seg = np.asarray(block_seg)
+    if not np.array_equal(np.asarray(block_base),
+                          (np.arange(nb) - lo[seg]) * block):
+        raise ValueError("block_base must be each row's offset in its slot")
+    size = np.asarray(block_size, np.int32)[lo]
+    rows = _rows(nb)
+    vec = _vec_spec(n_seg)
+    cap = cap_per_block
     vals, idx, cnt = pl.pallas_call(
-        kern,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_seg), lambda i: (0, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, cap_per_block), lambda i: (i, 0)),
-            pl.BlockSpec((1, cap_per_block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
+        functools.partial(_compact_kernel, cap=cap),
+        grid=(pl.cdiv(nb, rows),),
+        in_specs=[vec, vec, vec, vec, _row_spec(rows, block)],
+        out_specs=[_row_spec(rows, cap), _row_spec(rows, cap),
+                   _row_spec(rows, 1)],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, cap_per_block), jnp.float32),
-            jax.ShapeDtypeStruct((nb, cap_per_block), jnp.int32),
+            jax.ShapeDtypeStruct((nb, cap), jnp.float32),
+            jax.ShapeDtypeStruct((nb, cap), jnp.int32),
             jax.ShapeDtypeStruct((nb, 1), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((rows, block), jnp.int32),
+                        pltpu.VMEM((rows, 1), jnp.int32),
+                        pltpu.VMEM((rows, 1), jnp.int32)],
         interpret=resolve_interpret(interpret),
-    )(as_col(block_seg), as_col(block_base), as_col(block_size),
-      thresholds.astype(jnp.float32).reshape(1, n_seg), x2d)
+    )(_vec(lo), _vec(hi), _vec(size), _vec(thresholds, jnp.float32), x2d)
     return vals, idx, cnt[:, 0]
 
 
-def _resid_kernel(*refs, n_seg: int, momentum: float, nesterov: bool,
-                  weight_decay: float, round_dtype, has_p: bool):
+def _resid_kernel(*refs, momentum: float, nesterov: bool,
+                  weight_decay: float, round_dtype, has_u: bool,
+                  has_p: bool):
     it = iter(refs)
-    seg_ref = next(it)
-    g_ref = next(it)
-    v_ref = next(it)
-    u_ref = next(it) if momentum else None
+    lo_ref, hi_ref, g_ref, v_ref = (next(it) for _ in range(4))
+    u_ref = next(it) if has_u else None
     p_ref = next(it) if has_p else None
     v_out = next(it)
-    u_out = next(it) if momentum else None
-    sum_ref = next(it)
-    max_ref = next(it)
-
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        sum_ref[...] = jnp.zeros(sum_ref.shape, sum_ref.dtype)
-        max_ref[...] = jnp.zeros(max_ref.shape, max_ref.dtype)
+    u_out = next(it) if has_u else None
+    sum_ref, max_ref = next(it), next(it)
 
     g = g_ref[...].astype(jnp.float32)
-    if weight_decay:
+    if has_p:
         g = g + weight_decay * p_ref[...].astype(jnp.float32)
     v = v_ref[...]
-    if momentum:
+    if has_u:
         u = momentum * u_ref[...] + g
         v_new = v + u
         if nesterov:
@@ -317,12 +404,7 @@ def _resid_kernel(*refs, n_seg: int, momentum: float, nesterov: bool,
     if round_dtype is not None:
         v_new = v_new.astype(round_dtype).astype(jnp.float32)
     v_out[...] = v_new
-
-    ax = jnp.abs(v_new)
-    hit = _lane(n_seg) == seg_ref[0, 0]
-    sum_ref[...] += jnp.where(hit, jnp.sum(ax), 0.0)
-    max_ref[...] = jnp.maximum(max_ref[...],
-                               jnp.where(hit, jnp.max(ax), 0.0))
+    _accumulate_stats(lo_ref, hi_ref, sum_ref, max_ref, jnp.abs(v_new))
 
 
 def seg_residual_update_stats(
@@ -342,55 +424,76 @@ def seg_residual_update_stats(
     """Fused Alg 4 accumulation + Alg 2/3 statistics in ONE arena pass.
 
     Returns (V' [nb, block], U' or None, per-seg sum|V'|, per-seg
-    max|V'|). ``round_dtype`` rounds V' through the residual storage
-    dtype (bf16 residuals) before statistics, matching the per-leaf
-    store-then-reload sequence bitwise. ``u2d`` is required iff
-    ``momentum`` is nonzero; ``p2d`` iff ``weight_decay`` is nonzero.
+    max|V'|). The velocity update ``U' = momentum * U + g`` runs iff
+    ``u2d`` is given (required when ``momentum`` is nonzero); ``p2d``
+    is required iff ``weight_decay`` is nonzero. ``round_dtype`` rounds
+    V' through the residual storage dtype (bf16 residuals) before
+    statistics, matching the per-leaf store-then-reload sequence.
     """
     nb, block = g2d.shape
     if momentum and u2d is None:
         raise ValueError("momentum accumulation needs the velocity arena")
     if weight_decay and p2d is None:
         raise ValueError("weight decay needs the parameter arena")
-    seg = jnp.asarray(block_seg, jnp.int32).reshape(nb, 1)
-    row = pl.BlockSpec((1, block), lambda i: (i, 0))
-    acc = pl.BlockSpec((1, n_seg), lambda i: (0, 0))
+    has_u, has_p = u2d is not None, bool(weight_decay)
+    lo, hi = _bounds(block_seg, n_seg)
+    rows = _rows(nb)
+    row = _row_spec(rows, block)
+    vec = _vec_spec(n_seg)
 
-    ins = [seg, g2d, v2d]
-    in_specs = [pl.BlockSpec((1, 1), lambda i: (i, 0)), row, row]
-    if momentum:
+    ins = [_vec(lo), _vec(hi), g2d, v2d]
+    in_specs = [vec, vec, row, row]
+    if has_u:
         ins.append(u2d)
         in_specs.append(row)
-    if weight_decay:
+    if has_p:
         ins.append(p2d)
         in_specs.append(row)
-    out_specs = [row]
-    out_shape = [jax.ShapeDtypeStruct((nb, block), jnp.float32)]
-    if momentum:
-        out_specs.append(row)
-        out_shape.append(jax.ShapeDtypeStruct((nb, block), jnp.float32))
-    out_specs += [acc, acc]
-    out_shape += [jax.ShapeDtypeStruct((1, n_seg), jnp.float32),
-                  jax.ShapeDtypeStruct((1, n_seg), jnp.float32)]
-
+    planes = 1 + has_u
+    out_shape = ([jax.ShapeDtypeStruct((nb, block), jnp.float32)] * planes
+                 + [jax.ShapeDtypeStruct((1, n_seg), jnp.float32)] * 2)
     kern = functools.partial(
-        _resid_kernel, n_seg=n_seg, momentum=momentum, nesterov=nesterov,
-        weight_decay=weight_decay, round_dtype=round_dtype,
-        has_p=bool(weight_decay))
+        _resid_kernel, momentum=momentum, nesterov=nesterov,
+        weight_decay=weight_decay, round_dtype=round_dtype, has_u=has_u,
+        has_p=has_p)
     outs = pl.pallas_call(
-        kern, grid=(nb,), in_specs=in_specs, out_specs=out_specs,
+        kern, grid=(pl.cdiv(nb, rows),), in_specs=in_specs,
+        out_specs=[row] * planes + [vec, vec],
         out_shape=out_shape, interpret=resolve_interpret(interpret),
     )(*ins)
-    outs = list(outs)
-    v_new = outs.pop(0)
-    u_new = outs.pop(0) if momentum else None
-    sums, maxs = outs
+    v_new = outs[0]
+    u_new = outs[1] if has_u else None
+    sums, maxs = outs[-2:]
     return v_new, u_new, sums[0], maxs[0]
 
 
 # ---------------------------------------------------------------------------
 # Segmented selectors (Algorithm 2/3 across all slots at once)
 # ---------------------------------------------------------------------------
+
+def _cap_for(capacity: int, nb: int, block: int) -> int:
+    """Per-row bucket size for gathering ``capacity`` survivors: 4x the
+    uniform per-row share, rounded to the 8-sublane granule, clamped to
+    the block."""
+    per = -(-capacity // nb)
+    return min(block, max(8, ((4 * per + 7) // 8) * 8))
+
+
+def _gather_topk_from_buckets(vals, idx, k: int, total: int,
+                              order_by_magnitude: bool):
+    """Pick k entries from the [nb, cap] buckets: by |value| (trimmed top-k)
+    or simply the first-k valid slots (binary-search filter)."""
+    fv, fi = vals.reshape(-1), idx.reshape(-1)
+    valid = fi < total
+    if order_by_magnitude:
+        score = jnp.where(valid, jnp.abs(fv), -1.0)
+    else:
+        score = valid.astype(jnp.float32)
+    _, pos = jax.lax.top_k(score, k)
+    sel_idx = jnp.where(valid[pos], fi[pos], total)
+    sel_val = jnp.where(valid[pos], fv[pos], 0.0)
+    return sel_idx.astype(jnp.int32), sel_val
+
 
 def seg_mean(sums: jax.Array, geom, stride_seg=None) -> jax.Array:
     """Per-segment mean from per-segment sums — the pinned reciprocal
@@ -668,11 +771,12 @@ def multi_select(
                 si, sv = _gather_topk_from_buckets(
                     vals[row0:row1, :cap], idx[row0:row1, :cap], k, size,
                     order_by_magnitude=True)
-                overflow = jnp.any(cnts[row0:row1] > cap)
+                # too few survivors (the ladder bottomed out) or a
+                # dropped one (bucket overflow): the buckets cannot
+                # yield the top-k
+                fallback = jnp.any(cnts[row0:row1] > cap) | (nnz_loop[s] < k)
                 if use_pallas:
-                    # mirror ops.trimmed_topk: exact fallback on overflow
-                    fallback = overflow
-
+                    # mirror ops.trimmed_topk: exact top-k
                     def exact(_, sl=sl, k=k, x2d=x2d, geom=geom):
                         from repro.core.selection import exact_topk
                         e = exact_topk(_slot_flat(x2d, geom, sl), k)
@@ -681,8 +785,6 @@ def multi_select(
                     # mirror selection.trimmed_topk (no buckets at all):
                     # the full top-k pads with real zero-score indices
                     # when nnz < k
-                    fallback = overflow | (nnz_loop[s] < k)
-
                     def exact(_, sl=sl, k=k, t=thr[s], x2d=x2d, geom=geom):
                         from repro.core.selection import _pad_topk
                         flat = _slot_flat(x2d, geom, sl)

@@ -1,4 +1,4 @@
-"""Production mesh factory (TPU v5e target).
+"""Mesh factories (TPU v5e target).
 
 Single pod: 16x16 = 256 chips, axes ("data", "model").
 Multi-pod:  2x16x16 = 512 chips, axes ("pod", "data", "model") — the "pod"
@@ -12,16 +12,10 @@ from __future__ import annotations
 
 import jax
 
-# jax < 0.4.38 has no explicit axis types; Auto is its only behavior, so
-# omitting axis_types there is equivalent
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def _make_mesh(shape, axes):
-    if _AXIS_TYPE is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(_AXIS_TYPE.Auto,) * len(shape))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -31,5 +25,27 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(data: int = 4, model: int = 2):
-    """Small mesh over forced host devices (tests / examples)."""
+    """Small ("data", "model") mesh over host devices (tests / examples)."""
     return _make_mesh((data, model), ("data", "model"))
+
+
+def make_data_mesh(num_devices: int | None = None):
+    """Pure data-parallel 1-D ``("data",)`` mesh over the first
+    ``num_devices`` of ``jax.devices()`` (all when None) — the paper's
+    setting: params replicated, one worker per device."""
+    n = len(jax.devices()) if num_devices is None else num_devices
+    return _make_mesh((n,), ("data",))
+
+
+def mesh_from_spec(spec: str | None):
+    """The launcher's ``--mesh`` value as a mesh: None -> single device
+    (no mesh); ``pod`` / ``2pod`` -> production meshes; ``Dx1`` -> the
+    pure ``("data",)`` mesh over D devices; ``DxM`` -> ("data", "model")."""
+    if not spec:
+        return None
+    if spec == "pod":
+        return make_production_mesh(multi_pod=False)
+    if spec == "2pod":
+        return make_production_mesh(multi_pod=True)
+    d, m = (int(x) for x in spec.split("x"))
+    return make_data_mesh(d) if m == 1 else make_host_mesh(d, m)

@@ -1,9 +1,11 @@
 """Training launcher.
 
-On real TPU pods this runs under the production mesh; on this CPU container
-it drives the same code path at smoke scale (``--smoke`` configs, optional
-forced host devices via --host-devices, which must be set before jax init —
-hence the env var dance at the top).
+On TPU it runs on ``jax.devices()``: one chip without ``--mesh``, the
+pure data-parallel ``("data",)`` mesh with ``--mesh Dx1``, a ("data",
+"model") mesh with ``--mesh DxM``, the production meshes with ``pod`` /
+``2pod``. On CPU it drives the same code path at smoke scale
+(``--smoke`` configs, forced host devices via ``REPRO_HOST_DEVICES``,
+appended to ``XLA_FLAGS`` before jax initializes its backends).
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b --smoke \
@@ -11,25 +13,24 @@ Examples:
   REPRO_HOST_DEVICES=8 PYTHONPATH=src python -m repro.launch.train \
       --arch rwkv6-3b --smoke --mesh 4x2 --steps 20
 """
+import argparse
 import os
 
-if os.environ.get("REPRO_HOST_DEVICES"):
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count="
-        + os.environ["REPRO_HOST_DEVICES"])
-
-import argparse
-
 import jax
-import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, TrainConfig, get_config
 from repro.data import SyntheticLM, bigram_batches
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import mesh_from_spec
 from repro.train.trainer import Trainer
 
 
 def main() -> None:
+    if os.environ.get("REPRO_HOST_DEVICES"):
+        os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+            os.environ.get("XLA_FLAGS"),
+            "--xla_force_host_platform_device_count="
+            + os.environ["REPRO_HOST_DEVICES"]]))
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS + ("paper-lstm",),
                     required=True)
@@ -60,13 +61,14 @@ def main() -> None:
                     "delayed double-buffered sync), or a parameterized "
                     "spec like 'staleK(k=4)' (K-step-delayed ring)")
     ap.add_argument("--backend", default=None, choices=["jnp", "pallas"],
-                    help="selection-kernel backend (pallas auto-compiles "
-                    "on TPU, interprets elsewhere)")
+                    help="selection-kernel backend (pallas compiles on "
+                    "TPU, interprets on CPU)")
     ap.add_argument("--density", type=float, default=0.01)
     ap.add_argument("--momentum", type=float, default=0.9)
     ap.add_argument("--warmup-steps-per-stage", type=int, default=0)
     ap.add_argument("--mesh", default=None,
-                    help="DxM over host devices (e.g. 4x2); 'pod' or "
+                    help="DxM over the devices (e.g. 4x2); Dx1 is the "
+                    "pure data-parallel ('data',) mesh; 'pod' or "
                     "'2pod' for the production meshes")
     ap.add_argument("--data", default="bigram", choices=["bigram", "zipf"])
     ap.add_argument("--ckpt-dir", default=None)
@@ -85,15 +87,9 @@ def main() -> None:
     from repro.core.overlap import make_schedule
     make_schedule(args.schedule)
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
-    mesh = None
-    if args.mesh == "pod":
-        mesh = make_production_mesh(multi_pod=False)
-    elif args.mesh == "2pod":
-        mesh = make_production_mesh(multi_pod=True)
-    elif args.mesh:
-        d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = make_host_mesh(d, m)
+    mesh = mesh_from_spec(args.mesh)
 
     tc = TrainConfig(lr=args.lr, momentum=args.momentum,
                      optimizer=args.optimizer, transport=args.transport,

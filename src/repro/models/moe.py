@@ -157,13 +157,6 @@ def _auto_batch_axes(tokens: int) -> tuple[str, ...]:
     from .common import structural_shardmap_enabled
     if not structural_shardmap_enabled():
         return ()
-    # older jax lacks abstract-mesh introspection and/or the modern
-    # shard_map (which the baxes branch below calls without a mesh):
-    # fall back to global-capacity dispatch, which is always correct
-    if not hasattr(jax.sharding, "get_abstract_mesh") \
-            or not hasattr(jax.sharding, "AxisType") \
-            or not hasattr(jax, "shard_map"):
-        return ()
     am = jax.sharding.get_abstract_mesh()
     out = []
     size = 1
@@ -192,8 +185,7 @@ def moe_ffn(cfg: ModelConfig, p: dict, x: jax.Array) -> tuple[jax.Array, jax.Arr
     xt = x.reshape(b * s, d)
     baxes = _auto_batch_axes(b * s)
     if baxes:
-        from repro.jaxcompat import shard_map as shard_map_compat
-        out, aux = shard_map_compat(
+        out, aux = jax.shard_map(
             lambda pp, xx: _moe_local(cfg, pp, xx),
             axis_names=set(baxes),
             in_specs=(P(), P(baxes)),

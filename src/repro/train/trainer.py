@@ -25,7 +25,7 @@ owns the density schedule (Trainer.density_at defers to it).
 Pure data-parallel meshes (no "model" axis — the simulated-cluster
 harness, tests/harness/) take a single FULLY-manual shard_map over the
 batch axes: params replicated, batch sharded, gradients local. No nested
-partial-manual region, so this path also runs on legacy jax.
+partial-manual region.
 
 Single-device smoke mode (mesh=None): same code path, sync_axes=(), no
 shard_map — used by CPU tests; the RGC algebra is identical with p=1.
@@ -43,7 +43,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ParallelConfig, TrainConfig
 from repro.core.gradient_sync import GradientSync, build_gradient_sync
-from repro.jaxcompat import shard_map as shard_map_compat
 from repro.core.rgc import RGCConfig
 from repro.core.schedule import DensitySchedule
 from repro.models.common import param_specs
@@ -158,8 +157,7 @@ def make_train_step(
         # Pure data-parallel mesh (the simulated-cluster harness): one
         # FULLY-manual shard_map over the batch axes — params replicated,
         # batch sharded, gradients local — with no nested partial-manual
-        # region, so it also runs on legacy jax (same pattern as the
-        # test_distributed "oracle" case).
+        # region (same pattern as the test_distributed "oracle" case).
         bspec = P(baxes)
         batch_struct = model.train_inputs(1, 1)   # keys only
         batch_specs = {k: bspec for k in batch_struct}
@@ -170,7 +168,7 @@ def make_train_step(
                 grads, rgc_state, params, lr, density=dens)
             return jax.lax.pmean(loss, baxes), new_params, new_state
 
-        stepped = shard_map_compat(
+        stepped = jax.shard_map(
             flat_step, mesh=mesh, axis_names=set(baxes),
             in_specs=(P(), P(), batch_specs, P()),
             out_specs=(P(), P(), P()),
@@ -201,13 +199,12 @@ def make_train_step(
 
     def outer(params, rgc_state, batch, lr):
         loss, grads = jax.value_and_grad(model.loss)(params, batch)
-        new_params, new_state = shard_map_compat(
+        new_params, new_state = jax.shard_map(
             inner_sync,
             axis_names={"model"},
             in_specs=(pspecs, pspecs, sspecs, P()),
             out_specs=(pspecs, sspecs),
             check_vma=False,
-            fallback_mesh=mesh,
         )(grads, params, rgc_state, lr)
         return jax.lax.pmean(loss, baxes), new_params, new_state
 
@@ -217,7 +214,7 @@ def make_train_step(
     # In the outer shard_map only batch axes are manual; params / state / lr
     # are replicated across them (P() prefix specs); the model axis stays
     # auto (GSPMD) — model sharding rides on the array shardings.
-    stepped = shard_map_compat(
+    stepped = jax.shard_map(
         outer, mesh=mesh, axis_names=set(baxes),
         in_specs=(P(), P(), batch_specs, P()),
         out_specs=(P(), P(), P()),

@@ -26,7 +26,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import build_gradient_sync
-from repro.jaxcompat import shard_map as shard_map_compat
 from repro.launch.mesh import _make_mesh
 
 STEPS = 3
@@ -57,7 +56,7 @@ def run_steps(fuse, sizes, optimizer="rgc", **kw):
             p, st = sync.update(g_t, st, p, jnp.float32(LR))
         return p, st
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         worker, mesh=mesh,
         in_specs=({k: P(("data",)) for k in sizes}, P(),
                   jax.tree.map(lambda _: P(), state0)),

@@ -77,8 +77,7 @@ def test_sparse_allgather_oracle():
         return new_p["w"], new_st["w"]
 
     st0 = rgc_init({"w": params}, cfg)["w"]
-    from repro.jaxcompat import shard_map as shard_map_compat
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         worker, mesh=mesh,
         in_specs=(P("data"), P(), jax.tree.map(lambda _: P(), st0)),
         out_specs=(P(), jax.tree.map(lambda _: P(), st0)),

@@ -44,7 +44,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import build_gradient_sync
-from repro.jaxcompat import shard_map as shard_map_compat
 from repro.launch.mesh import _make_mesh
 
 STEPS = 3
@@ -96,7 +95,7 @@ def run_steps(schedule, transport, fuse, optimizer="rgc", **kw):
             p, st = sync.update(g_t, st, p, jnp.float32(LR))
         return p, st
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         worker, mesh=mesh,
         in_specs=({k: P(axes) for k in TREE_SIZES}, P(),
                   jax.tree.map(lambda _: P(), state0)),
@@ -205,7 +204,7 @@ def delayed_case(schedule, delay):
             st = jax.tree.unflatten(treedef, new_states)
         return p, st
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         worker, mesh=mesh,
         in_specs=({k: P(axes) for k in TREE_SIZES}, P(),
                   jax.tree.map(lambda _: P(), state0)),

@@ -30,7 +30,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import build_gradient_sync
 from repro.core import sync as sync_lib
-from repro.jaxcompat import shard_map as shard_map_compat
 from repro.launch.mesh import _make_mesh
 
 STEPS = 3
@@ -72,7 +71,7 @@ def run_steps(transport, axes, sizes, **transport_kw):
             p, st = sync.update(g_t, st, p, jnp.float32(LR))
         return p, st
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         worker, mesh=mesh,
         in_specs=({k: P(axes) for k in sizes}, P(),
                   jax.tree.map(lambda _: P(), state0)),
@@ -104,7 +103,7 @@ def test_row_order():
         hier = sync_lib.hierarchical_allgather(x[0], ("node",), "local")
         return (flat == hier).all(), flat[:, 0]
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(jax.shard_map(
         worker, mesh=mesh, in_specs=(P(("node", "local")),),
         out_specs=(P(), P()), check_vma=False))
     # tag each worker's message with its global rank

@@ -5,7 +5,7 @@ The harness splits one host CPU into N XLA devices
 mesh over them — flat ``("data",)`` or, for the hierarchical transport,
 2-axis ``("node", "local")`` — and drives real training loops through
 ``repro.train.trainer.Trainer`` — the trainer's fully-manual shard_map
-path, which runs on both legacy (0.4.x) and modern jax. Each worker sees
+path. Each worker sees
 its own batch shard and computes LOCAL gradients, so the residual /
 correction / selection / allgather pipeline is exercised exactly as on a
 real cluster (p = N in Eq 1), just without the wire.
@@ -61,23 +61,17 @@ def check(name: str, cond: bool) -> None:
 
 
 def subprocess_env(extra: dict[str, str] | None = None) -> dict[str, str]:
-    """Environment for harness/test subprocesses: repo src + tests on path."""
+    """Environment for harness/test subprocesses: repo src + tests on
+    path, pinned to the CPU platform — they simulate their cluster on
+    forced host devices, and an accelerator belongs to the parent."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     path = [SRC_DIR, TESTS_DIR]
     if env.get("PYTHONPATH"):
         path.append(env["PYTHONPATH"])
     env["PYTHONPATH"] = os.pathsep.join(path)
     env.update(extra or {})
     return env
-
-
-def make_data_mesh(num_devices: int | None = None):
-    """1-D ``("data",)`` mesh over the (forced) host devices."""
-    import jax
-
-    from repro.launch.mesh import _make_mesh
-    n = len(jax.devices()) if num_devices is None else num_devices
-    return _make_mesh((n,), ("data",))
 
 
 def make_node_mesh(nodes: int = 2, local: int | None = None):
@@ -180,6 +174,7 @@ def train_and_eval(
                  if v is not None}
     if overrides:
         tc = dataclasses.replace(tc, **overrides)
+    from repro.launch.mesh import make_data_mesh
     if not use_mesh:
         mesh = None
     elif nodes is not None:

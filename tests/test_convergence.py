@@ -16,8 +16,7 @@ ramp); the DGC density ramp is exercised under the looser half-progress
 bar the paper's Tab 1 analogue (benchmarks/tab1_convergence.py) also uses.
 
 Slow (minutes per case): marked ``tier2``, skipped unless ``--run-tier2``
-/ ``RUN_TIER2=1`` (CI runs these in their own job, modern-jax leg only —
-though the harness's fully-manual mesh path also runs on legacy jax).
+/ ``RUN_TIER2=1`` (CI runs these in their own job).
 """
 import pytest
 

@@ -5,26 +5,11 @@ be set before jax initializes — so each case runs tests/_dist_prog.py in a
 subprocess through the shared ``run_prog`` fixture (tests/conftest.py)."""
 import os
 
-import jax
 import pytest
 
 _PROG = os.path.join(os.path.dirname(__file__), "_dist_prog.py")
 
-# The trainer's nested partial-manual shard_map (manual data axes, auto
-# model axis, GSPMD constraints inside) needs the modern jax.shard_map /
-# XLA; the legacy experimental API's SPMD partitioner aborts with
-# "Check failed: sharding.IsManualSubgroup()". The fully-manual oracle
-# case runs everywhere.
-_legacy_jax = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="nested partial-manual shard_map requires modern jax/XLA")
 
-
-@pytest.mark.parametrize("case", [
-    pytest.param("dense", marks=_legacy_jax),
-    "oracle",
-    pytest.param("variants", marks=_legacy_jax),
-    pytest.param("multipod", marks=_legacy_jax),
-])
+@pytest.mark.parametrize("case", ["dense", "oracle", "variants", "multipod"])
 def test_distributed(case, run_prog):
     run_prog(_PROG, case)

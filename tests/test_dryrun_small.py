@@ -3,14 +3,6 @@ extraction) on a small host mesh, via the shared ``run_prog`` subprocess
 fixture (device-count flag must precede jax init)."""
 import os
 
-import jax
-import pytest
 
-
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="nested partial-manual shard_map requires modern jax/XLA "
-    "(legacy SPMD partitioner aborts on the trainer's mixed "
-    "manual/auto pattern)")
 def test_dryrun_small_mesh(run_prog):
     run_prog(os.path.join(os.path.dirname(__file__), "_dryrun_prog.py"))

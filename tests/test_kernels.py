@@ -7,10 +7,7 @@ import pytest
 
 from repro.core import selection as sel
 from repro.kernels import ops, ref
-from repro.kernels.block_stats import abs_sum_max
-from repro.kernels.compact import compact_gt
-from repro.kernels.threshold_count import count_gt
-from repro.kernels.residual_update import residual_update
+from repro.kernels.ops import abs_sum_max, compact_gt, count_gt
 
 SHAPES = [(4, 128), (8, 256), (3, 1024), (16, 512), (1, 128)]
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -19,6 +16,23 @@ DTYPES = [jnp.float32, jnp.bfloat16]
 def _x2d(shape, dtype, seed=0):
     rng = np.random.default_rng(seed)
     return jnp.asarray(rng.standard_normal(shape), dtype)
+
+
+class TestResolveInterpret:
+    """Interpret only on CPU, compile on TPU, refuse anything else."""
+
+    @pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False)])
+    def test_by_backend(self, monkeypatch, backend, want):
+        from repro.kernels import segmented as kseg
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert kseg.resolve_interpret(None) is want
+        assert kseg.resolve_interpret(not want) is (not want)
+
+    def test_other_backend_raises(self, monkeypatch):
+        from repro.kernels import segmented as kseg
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="target TPU"):
+            kseg.resolve_interpret(None)
 
 
 class TestBlockStats:
