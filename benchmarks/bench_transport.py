@@ -266,8 +266,7 @@ def selection_attack(params, grads, *, iters: int) -> dict:
 
     Every variant routes ALL sparse leaves through Alg 3 (the selector
     the attack targets); the dispatch counter records select launches
-    (one per leaf cold -> ONE per step fused) and ``select_overflow``
-    surfaces pinned capacity overflows of the threshold filter.
+    (one per leaf cold -> ONE per step fused).
     """
     from repro.core import build_gradient_sync
 
@@ -306,7 +305,6 @@ def selection_attack(params, grads, *, iters: int) -> dict:
             "select_total_s": summ["stages"]["select"]["total_s"],
             "select_dispatches_per_step":
                 summ["counts"].get("dispatch_select", 0) / iters,
-            "select_overflow": summ["counts"].get("select_overflow", 0),
         }
     return {"iters": iters, "baseline_share": SELECT_BASELINE_SHARE,
             "variants": out}
@@ -383,12 +381,11 @@ def main(quick: bool = False, schedule: str = "chunked") -> dict:
 
     attack = selection_attack(params, grads, iters=iters)
     print("selection_attack,variant,select_share,select_ms,"
-          "select_dispatches_per_step,select_overflow")
+          "select_dispatches_per_step")
     for label, row in attack["variants"].items():
         print(f"selection_attack,{label},{row['select_share']:.3f},"
               f"{row['select_total_s'] * 1e3:.2f},"
-              f"{row['select_dispatches_per_step']:.1f},"
-              f"{row['select_overflow']}")
+              f"{row['select_dispatches_per_step']:.1f}")
 
     predicted = {}
     for net in (PIZ_DAINT, TPU_V5E):

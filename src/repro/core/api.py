@@ -29,10 +29,11 @@ four swappable protocols, each string-addressable via
     and the transports: each pipeline stage (``accumulate`` / ``select``
     / ``mask`` / ``pack`` / ``transfer`` / ``unpack`` — Fig 10's
     decomposition with the paper's "mask" bar split) runs inside
-    ``timer.stage(name, thunk)``; ``timer.count`` records
-    ``dispatch_<stage>`` fused-operation launches and transport
-    collective/message counts. ``repro.core.instrument`` ships
-    ``NullTimer`` (free, trace-safe default) and ``WallClockTimer``
+    ``timer.stage(name, thunk)``, which runs it under
+    ``jax.named_scope(name)`` so the compiled step names each stage;
+    ``timer.count`` records ``dispatch_<stage>`` fused-operation launches
+    and transport collective/message counts. ``repro.core.instrument``
+    ships ``NullTimer`` (free, trace-safe default) and ``WallClockTimer``
     (barriered wall-clock sampling for eager benchmark runs).
 
 ``DispatchPolicy``
@@ -115,16 +116,19 @@ class Compressor(Protocol):
 @runtime_checkable
 class StageTimer(Protocol):
     """Pipeline stage instrumentation (Fig 10's mask/select/pack/transfer/
-    unpack decomposition). ``stage`` executes and may time a stage body;
-    ``count`` records dimensionless facts (collectives per step, bucket
-    counts). Implementations: ``instrument.NullTimer`` (default; ``stage``
-    is a bare passthrough, safe under tracing) and
-    ``instrument.WallClockTimer`` (eager-mode barriered timing)."""
+    unpack decomposition). ``stage`` executes a stage body under
+    ``jax.named_scope(name)``, so the compiled step and its profiler
+    trace name the stage, and may time it; ``count`` records
+    dimensionless facts (collectives per step, bucket counts).
+    Implementations: ``instrument.NullTimer`` (default; scope only, safe
+    under tracing) and ``instrument.WallClockTimer`` (eager-mode
+    barriered timing)."""
 
     active: bool
 
     def stage(self, name: str, thunk: Any) -> Any:
-        """Run ``thunk()`` as pipeline stage ``name``; return its value."""
+        """Run ``thunk()`` as pipeline stage ``name`` (under the named
+        scope ``name``); return its value."""
         ...
 
     def count(self, name: str, n: int = 1) -> None:

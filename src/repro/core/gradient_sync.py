@@ -507,18 +507,6 @@ class GradientSync:
         return timer.stage("pack",
                            lambda: arena.pack_group(group, selected))
 
-    def _count_overflow(self, selections) -> None:
-        """Surface ``threshold_filter`` capacity overflows (§pinned
-        semantics: first-``capacity`` lowest-index survivors kept, count
-        saturated) on the stage timer. Eager-only — under jit the flags
-        are tracers and the counter stays silent (NullTimer is free)."""
-        if not getattr(self.timer, "active", False):
-            return
-        for sel in selections:
-            ovf = getattr(sel, "overflow", None)
-            if ovf is not None and not isinstance(ovf, jax.core.Tracer):
-                self.timer.count("select_overflow", int(bool(ovf)))
-
     def update(self, grads: Any, state: Any, params: Any, lr: jax.Array,
                *, density: float | None = None) -> tuple[Any, Any]:
         """One synchronized step. Returns (new_params, new_state).
@@ -585,8 +573,6 @@ class GradientSync:
             results = timer.stage(
                 "select", lambda: self._select_groups(
                     plan.groups, plan.group_comps, accs))
-            self._count_overflow(
-                s for sel, _ in results for s in sel)
             for (group, comp), (sel, slot_states), acc in zip(
                     zip(plan.groups, plan.group_comps), results, accs):
                 messages.append(self._finish_group(
@@ -602,7 +588,6 @@ class GradientSync:
             timer.count("dispatch_select")
             selected, st = timer.stage(
                 "select", lambda f=flat_v, st=st: comp.compress(f, k, st))
-            self._count_overflow([selected])
 
             def _mask(st=st, sel=selected):
                 st2 = mask_communicated(st, sel.indices, momentum=False)

@@ -19,10 +19,29 @@ one per ARENA with ``fuse_leaves``) — and the transports count
 ``collectives`` / ``messages``; these are the O(leaves) → O(arenas)
 facts ``benchmarks/bench_transport.py`` asserts on.
 
-Two implementations:
+Both implementations run each stage's thunk under
+``jax.named_scope(<stage>)``, so the stages are named in the compiled
+step: every instruction a stage emits carries it in its ``op_name``
+(``jit(step)/sync/select/...``), and so does each op event of a profiler
+trace of that step. The trainer adds ``fwd_bwd`` (the model's loss and
+gradients) and ``sync`` (``GradientSync.update``) around them, and
+``kernels.segmented.multi_select`` adds ``search`` (one survivor count of
+the bisection loop) and ``fallback`` (a slot's exact selection after its
+buckets overflow) inside ``select``. Every schedule and transport names
+its stages through this hook. To read the stages' device time, trace a
+few steps on the chip and reduce the trace by scope::
 
-* ``NullTimer`` — the default everywhere. ``stage`` just calls the thunk;
-  safe (and free) under ``jit``/``shard_map`` tracing.
+    with jax.profiler.trace(trace_dir):
+        state = trainer.run(state, batches, 5)
+
+then ``chipbench.scopes.reduce`` (``benchmarks/chip``) on the planes that
+``chipbench.tracing.load`` reads from ``trace_dir`` and the step's
+``compiled.as_text()``. A scope is metadata only: the compiled program
+is otherwise the same.
+
+* ``NullTimer`` — the default everywhere. ``stage`` calls the thunk
+  under the stage's scope; safe (and free) under ``jit``/``shard_map``
+  tracing.
 * ``WallClockTimer`` — wraps each stage with a ``jax.block_until_ready``
   barrier and accumulates wall time per stage. Only meaningful for EAGER
   (op-by-op) execution: under ``jit`` the thunk runs once at trace time
@@ -60,7 +79,8 @@ class NullTimer:
     active = False
 
     def stage(self, name: str, thunk: Callable[[], Any]) -> Any:
-        return thunk()
+        with jax.named_scope(name):
+            return thunk()
 
     def count(self, name: str, n: int = 1) -> None:
         pass
@@ -89,7 +109,8 @@ class WallClockTimer:
 
     def stage(self, name: str, thunk: Callable[[], Any]) -> Any:
         t0 = time.perf_counter()
-        out = thunk()
+        with jax.named_scope(name):
+            out = thunk()
         jax.block_until_ready(out)
         dt = time.perf_counter() - t0
         self.times[name].append(dt)
@@ -98,8 +119,8 @@ class WallClockTimer:
         return out
 
     def count(self, name: str, n: int = 1) -> None:
-        # coerce: callers may pass numpy/DeviceArray bools (e.g. the
-        # select_overflow flag) — keep the counter a python int
+        # coerce: callers may pass numpy/DeviceArray values — keep the
+        # counter a python int
         self.counts[name] += int(n)
 
     def set_lane(self, lane: str | None) -> None:

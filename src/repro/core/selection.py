@@ -333,11 +333,9 @@ def threshold_filter(x: jax.Array, threshold: jax.Array, capacity: int) -> Selec
     Overflow semantics (pinned): when ``nnz > capacity`` the first
     ``capacity`` survivors in *index* order are kept — lowest indices
     win, NOT the largest magnitudes — the ``count`` header saturates at
-    ``capacity``, and ``overflow`` is set so the pipeline can surface the
-    silent drop (GradientSync counts it as ``select_overflow`` on the
-    stage timer; the transport bench reports it). Shapes are static, so
-    the alternative — growing the message — does not exist; the flag is
-    the contract.
+    ``capacity``, and ``overflow`` is set to flag the silent drop.
+    Shapes are static, so the alternative — growing the message — does
+    not exist; the flag is the contract.
     """
     ax = jnp.abs(x)
     mask = ax > threshold

@@ -728,7 +728,8 @@ def multi_select(
         step = jnp.where(ta, step + 1, step)
         ratio_b = bisect_midpoint(l, r)
         ratio = jnp.where(is_trim, ladder_ratio(step, eps_vec), ratio_b)
-        cnt = count_est(threshold_at(mean, mx, ratio))
+        with jax.named_scope("search"):
+            cnt = count_est(threshold_at(mean, mx, ratio))
         nnz = jnp.where(ta | ba, cnt, nnz)
         r = jnp.where(ba & (cnt < k_vec), ratio_b, r)
         l = jnp.where(ba & (cnt > two_k), ratio_b, l)
@@ -779,7 +780,8 @@ def multi_select(
                     # mirror ops.trimmed_topk: exact top-k
                     def exact(_, sl=sl, k=k, x2d=x2d, geom=geom):
                         from repro.core.selection import exact_topk
-                        e = exact_topk(_slot_flat(x2d, geom, sl), k)
+                        with jax.named_scope("fallback"):
+                            e = exact_topk(_slot_flat(x2d, geom, sl), k)
                         return e.indices, e.values
                 else:
                     # mirror selection.trimmed_topk (no buckets at all):
@@ -787,10 +789,11 @@ def multi_select(
                     # when nnz < k
                     def exact(_, sl=sl, k=k, t=thr[s], x2d=x2d, geom=geom):
                         from repro.core.selection import _pad_topk
-                        flat = _slot_flat(x2d, geom, sl)
-                        score = jnp.where(jnp.abs(flat) > t,
-                                          jnp.abs(flat), 0.0)
-                        e = _pad_topk(flat, score, k)
+                        with jax.named_scope("fallback"):
+                            flat = _slot_flat(x2d, geom, sl)
+                            score = jnp.where(jnp.abs(flat) > t,
+                                              jnp.abs(flat), 0.0)
+                            e = _pad_topk(flat, score, k)
                         return e.indices, e.values
 
                 si, sv = jax.lax.cond(fallback, exact,
@@ -804,8 +807,9 @@ def multi_select(
                 overflow = jnp.any(cnts[row0:row1] > cap)
 
                 def exact(_, sl=sl, c=cap_sel, t=thr[s], x2d=x2d, geom=geom):
-                    e = threshold_filter(_slot_flat(x2d, geom, sl), t,
-                                         capacity=c)
+                    with jax.named_scope("fallback"):
+                        e = threshold_filter(_slot_flat(x2d, geom, sl), t,
+                                             capacity=c)
                     return e.indices, e.values
 
                 si, sv = jax.lax.cond(overflow, exact,

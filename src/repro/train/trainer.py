@@ -39,6 +39,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ParallelConfig, TrainConfig
@@ -124,6 +125,18 @@ def _leaf_state_specs(pspec: P, momentum: bool = True) -> Any:
     return LeafState(pspec, pspec if momentum else P(), P(), P(), P())
 
 
+def _fwd_bwd(model: Model, params, batch):
+    """Loss and local gradients, under the ``fwd_bwd`` scope."""
+    with jax.named_scope("fwd_bwd"):
+        return jax.value_and_grad(model.loss)(params, batch)
+
+
+def _sync_update(sync: GradientSync, grads, rgc_state, params, lr, dens):
+    """``GradientSync.update``, under the ``sync`` scope."""
+    with jax.named_scope("sync"):
+        return sync.update(grads, rgc_state, params, lr, density=dens)
+
+
 def make_train_step(
     model: Model,
     mesh: Optional[Mesh],
@@ -145,9 +158,9 @@ def make_train_step(
 
     if mesh is None:
         def step(params, rgc_state, batch, lr):
-            loss, grads = jax.value_and_grad(model.loss)(params, batch)
-            new_params, new_state = sync.update(
-                grads, rgc_state, params, lr, density=dens)
+            loss, grads = _fwd_bwd(model, params, batch)
+            new_params, new_state = _sync_update(
+                sync, grads, rgc_state, params, lr, dens)
             return loss, new_params, new_state
         return jax.jit(step, donate_argnums=(0, 1) if donate else ())
 
@@ -163,9 +176,9 @@ def make_train_step(
         batch_specs = {k: bspec for k in batch_struct}
 
         def flat_step(params, rgc_state, batch, lr):
-            loss, grads = jax.value_and_grad(model.loss)(params, batch)
-            new_params, new_state = sync.update(
-                grads, rgc_state, params, lr, density=dens)
+            loss, grads = _fwd_bwd(model, params, batch)
+            new_params, new_state = _sync_update(
+                sync, grads, rgc_state, params, lr, dens)
             return jax.lax.pmean(loss, baxes), new_params, new_state
 
         stepped = jax.shard_map(
@@ -195,10 +208,10 @@ def make_train_step(
     bspec = P(baxes)     # shard dim 0 over all batch axes
 
     def inner_sync(grads, params, rgc_state, lr):
-        return sync.update(grads, rgc_state, params, lr, density=dens)
+        return _sync_update(sync, grads, rgc_state, params, lr, dens)
 
     def outer(params, rgc_state, batch, lr):
-        loss, grads = jax.value_and_grad(model.loss)(params, batch)
+        loss, grads = _fwd_bwd(model, params, batch)
         new_params, new_state = jax.shard_map(
             inner_sync,
             axis_names={"model"},
@@ -272,7 +285,7 @@ def make_fsdp_dense_step(model: Model, mesh: Mesh, pc: ParallelConfig,
     def step(params, momentum, batch, lr):
         from repro.models.common import pure_gspmd
         with pure_gspmd():
-            loss, grads = jax.value_and_grad(model.loss)(params, batch)
+            loss, grads = _fwd_bwd(model, params, batch)
         new_m = jax.tree.map(
             lambda m, g: tc.momentum * m + g.astype(jnp.float32),
             momentum, grads)
@@ -448,23 +461,36 @@ class Trainer:
             on_metrics: Optional[Callable[[int, float, float], None]] = None
             ) -> TrainState:
         """``on_metrics(step, density, loss)`` fires every step (forces a
-        per-step device sync — metrics/convergence harness use)."""
+        per-step device sync — metrics/convergence harness use).
+
+        Each step is a ``train_step`` profiler step whose host spans are
+        ``trainer.feed`` (next batch onto the device), ``trainer.dispatch``
+        (the step call), ``trainer.metrics`` (``on_metrics`` and logging)
+        and ``trainer.checkpoint``; outside a profile they cost one check
+        each."""
         it = iter(batches)
         for _ in range(num_steps):
-            batch = {k: jnp.asarray(v) for k, v in next(it).items()}
-            density = self.density_at(state.step)
-            fn = self._step_fn(density)
-            loss, params, rgc_state = fn(
-                state.params, state.rgc, batch, jnp.float32(self.tc.lr))
-            state = TrainState(params, rgc_state, state.step + 1)
-            if on_metrics is not None:
-                on_metrics(state.step, density, float(loss))
-            if log_every and state.step % log_every == 0:
-                log_fn(f"step {state.step:5d}  density {density:.4%}  "
-                       f"loss {float(loss):.4f}")
-            if (self.ckpt_dir and self.ckpt_every
-                    and state.step % self.ckpt_every == 0):
-                self.save_checkpoint(state)
+            with StepTraceAnnotation("train_step", step_num=state.step):
+                with TraceAnnotation("trainer.feed"):
+                    batch = {k: jnp.asarray(v) for k, v in next(it).items()}
+                density = self.density_at(state.step)
+                fn = self._step_fn(density)
+                with TraceAnnotation("trainer.dispatch"):
+                    loss, params, rgc_state = fn(
+                        state.params, state.rgc, batch,
+                        jnp.float32(self.tc.lr))
+                state = TrainState(params, rgc_state, state.step + 1)
+                with TraceAnnotation("trainer.metrics"):
+                    if on_metrics is not None:
+                        on_metrics(state.step, density, float(loss))
+                    if log_every and state.step % log_every == 0:
+                        log_fn(f"step {state.step:5d}  density "
+                               f"{density:.4%}  loss {float(loss):.4f}")
+                if (self.ckpt_dir and self.ckpt_every
+                        and state.step % self.ckpt_every == 0):
+                    with TraceAnnotation("trainer.checkpoint"):
+                        self.save_checkpoint(state)
         if self.ckpt_dir and self._manager().latest_step() != state.step:
-            self.save_checkpoint(state)
+            with TraceAnnotation("trainer.checkpoint"):
+                self.save_checkpoint(state)
         return state
