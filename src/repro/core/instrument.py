@@ -26,7 +26,8 @@ step: every instruction a stage emits carries it in its ``op_name``
 trace of that step. The trainer adds ``fwd_bwd`` (the model's loss and
 gradients) and ``sync`` (``GradientSync.update``) around them, and
 ``kernels.segmented.multi_select`` adds ``search`` (one survivor count of
-the bisection loop) and ``fallback`` (a slot's exact selection after its
+the bisection loop), ``compact`` (the one exact compaction of every
+bsearch slot) and ``fallback`` (a trimmed slot's exact top-k after its
 buckets overflow) inside ``select``. Every schedule and transport names
 its stages through this hook. To read the stages' device time, trace a
 few steps on the chip and reduce the trace by scope::

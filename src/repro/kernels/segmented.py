@@ -14,12 +14,16 @@ identical to running the per-leaf selectors leaf by leaf:
 * ``seg_compact_gt``    — ``compact.compact_gt`` extended to per-segment
                           thresholds and slot-local indices: block-
                           bucketed compaction of every slot's survivors
-                          in one launch;
+                          in one launch (the trimmed selector's);
 * ``seg_residual_update_stats`` — the fused hot loop: momentum-corrected
                           residual accumulation (Alg 4 l.11-19) AND the
                           Alg 2/3 block statistics of the updated
                           residual in a single pass over the arena (one
                           HBM round-trip instead of two).
+
+``seg_first_gt`` (plain jnp, both backends) is the bsearch selector's
+compaction: every bsearch slot's first ``cap`` survivors in slot order,
+exactly, by prefix sums and bisections, with no bucket and no scatter.
 
 Bitwise parity rests on the arena layout: slots are ``ARENA_BLOCK``-
 aligned and zero-padded, so each slot's rows are exactly the 2-D view a
@@ -48,14 +52,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.selection import (Selected, bisect_midpoint, ladder_ratio,
-                                  threshold_at, threshold_filter, warm_ratio)
+                                  threshold_at, warm_ratio)
 
 from . import ref
 
 __all__ = [
     "seg_abs_sum_max", "seg_count_gt", "seg_compact_gt",
     "seg_residual_update_stats", "seg_stats", "seg_mean",
-    "seg_counts", "SegmentSpec", "multi_select",
+    "seg_counts", "seg_first_gt", "SegmentSpec", "multi_select",
     "trimmed_topk_segments", "threshold_bsearch_segments",
 ]
 
@@ -553,6 +557,92 @@ def _seg_buckets(x2d, geom, thresholds, cap, *, use_pallas, interpret):
                               geom.block_size, thresholds, cap)
 
 
+def _lower_bound(fetch, target: jax.Array, lo, hi, steps: int
+                 ) -> jax.Array:
+    """Per query, the least ``i`` in ``[lo, hi]`` with ``fetch(i) >=
+    target``, for a non-decreasing table that reaches ``target`` at
+    ``hi``: ``steps`` halvings of one gather each (``steps`` must cover
+    the widest range, ``(hi - lo).bit_length()``). Results stay in
+    ``[lo, hi]`` whatever the targets, so every gather is in bounds."""
+    for _ in range(steps):
+        mid = (lo + hi) // 2
+        go = fetch(mid) >= target
+        hi = jnp.where(go, mid, hi)
+        lo = jnp.where(go, lo, mid + 1)
+    return lo
+
+
+def seg_first_gt(x2d: jax.Array, geom, thresholds: jax.Array,
+                 capacities, segments) -> dict[int, Selected]:
+    """Each listed segment's first ``capacities[s]`` elements with
+    ``|x| > thresholds[s]``, in slot order, padded with the slot size:
+    bitwise ``selection.threshold_filter`` of the slot, for all of them
+    in one exact, order-preserving compaction with no scatter.
+
+    The survivor mask (lanes past a slot's size left out) gives each
+    row's inclusive lane prefix ``pos`` and, from its last lane, the
+    row's count; a prefix of those counts ranks every row's survivors
+    across the arena. An output's wanted rank is then found by two
+    vectorized bisections — its row over the row prefix, within the
+    segment's rows, then its lane over that row's ``pos`` — and one
+    gather takes the value. Returns ``{segment: Selected}`` with
+    ``count = min(nnz, cap)`` and ``overflow = nnz > cap``.
+    """
+    block = x2d.shape[1]
+    x = x2d.astype(jnp.float32)
+    segs = [int(s) for s in segments]
+    on = np.zeros(geom.n_seg, bool)
+    on[segs] = True
+    # other segments' rows get an infinite threshold: no survivors
+    thr = jnp.where(jnp.asarray(on), jnp.asarray(thresholds, jnp.float32),
+                    jnp.inf)
+    thr_b = thr[jnp.asarray(np.asarray(geom.block_seg), jnp.int32)]
+    lidx = (jnp.asarray(np.asarray(geom.block_base), jnp.int32)[:, None]
+            + jnp.arange(block, dtype=jnp.int32)[None, :])
+    size_b = jnp.asarray(np.asarray(geom.block_size), jnp.int32)[:, None]
+    mask = (jnp.abs(x) > thr_b[:, None]) & (lidx < size_b)
+    # a row holds at most ``block`` survivors: int16 halves the one
+    # arena-sized integer array
+    pos = jnp.cumsum(mask, axis=1, dtype=jnp.int16)
+    cnt = pos[:, -1].astype(jnp.int32)
+    incl = jnp.cumsum(cnt)                      # survivors up to each row
+    excl = incl - cnt                           # survivors before it
+
+    # per output: the wanted arena-wide rank (1-based) and its row range
+    caps = [int(capacities[s]) for s in segs]
+    rows = [geom.seg_rows[s] for s in segs]
+    nnz = {s: incl[r1 - 1] - excl[r0] for s, (r0, r1) in zip(segs, rows)}
+    want, lo, hi = [], [], []
+    for s, c, (r0, r1) in zip(segs, caps, rows):
+        j = jnp.minimum(jnp.arange(c, dtype=jnp.int32),
+                        jnp.maximum(nnz[s] - 1, 0))
+        want.append(excl[r0] + j + 1)
+        lo.append(jnp.full((c,), r0, jnp.int32))
+        hi.append(jnp.full((c,), r1 - 1, jnp.int32))
+    want = jnp.concatenate(want)
+    row_steps = max((r1 - 1 - r0).bit_length() for r0, r1 in rows)
+    row = _lower_bound(lambda i: incl[i], want, jnp.concatenate(lo),
+                       jnp.concatenate(hi), row_steps)
+    col = _lower_bound(lambda c: pos[row, c],
+                       (want - excl[row]).astype(jnp.int16),
+                       jnp.zeros_like(row), jnp.full_like(row, block - 1),
+                       (block - 1).bit_length())
+    val = x[row, col]
+
+    out: dict[int, Selected] = {}
+    off = 0
+    for s, c, (r0, _r1) in zip(segs, caps, rows):
+        size = geom.seg_sizes[s]
+        ok = jnp.arange(c, dtype=jnp.int32) < nnz[s]
+        span = slice(off, off + c)
+        out[s] = Selected(
+            jnp.where(ok, (row[span] - r0) * block + col[span], size),
+            jnp.where(ok, val[span], 0.0),
+            jnp.minimum(nnz[s], c), nnz[s] > c)
+        off += c
+    return out
+
+
 def _slot_flat(x2d: jax.Array, geom, s: int) -> jax.Array:
     """Slot ``s`` as the flat f32[size] vector the per-leaf path sees."""
     r0, r1 = geom.seg_rows[s]
@@ -611,8 +701,15 @@ def multi_select(
     arenas. Converged (or reuse / warm-accepted) segments are FROZEN —
     their carried state stops updating — so each segment still walks
     exactly the iterate sequence its per-leaf selector would, and the
-    selected sets stay bitwise identical to the per-leaf path. Bucket
-    compaction is likewise one ``seg_compact_gt`` launch for everything.
+    selected sets stay bitwise identical to the per-leaf path.
+
+    Compaction follows the search, once for all arenas. Every bsearch
+    segment's message is its slot's ``threshold_filter`` at the final
+    threshold — the first ``cap`` survivors in slot order — and
+    ``seg_first_gt`` computes it exactly for all of them in one pass
+    (scope ``compact``), on either backend. Trimmed segments go through
+    one ``seg_compact_gt`` bucket launch and a top-k by magnitude, with
+    a per-slot exact ``fallback`` branch where a bucket overflowed.
 
     Returns one ``(selections, thresholds)`` pair per part, in order.
     """
@@ -746,78 +843,62 @@ def multi_select(
     if have_cached:
         thr = jnp.where(is_trim | refresh, thr, cached)
 
-    # --- one full count + one compaction for every arena ----------------
-    nnz_full = seg_counts(x_all, geom_all, thr, use_pallas=use_pallas,
-                          interpret=interpret)
-    caps = [_cap_for(2 * k if t else max(2 * k, c), r1 - r0, geom_all.block)
-            for t, k, c, (r0, r1) in zip(
-                trim_np, geom_all.seg_ks, caps_sel, geom_all.seg_rows)]
-    cap_max = max(caps)
-    vals, idx, cnts = _seg_buckets(x_all, geom_all, thr, cap_max,
-                                   use_pallas=use_pallas,
-                                   interpret=interpret)
+    # --- compaction: bsearch slots exactly, trimmed slots in buckets ----
+    bs_segs = np.flatnonzero(~trim_np)
+    if bs_segs.size:
+        with jax.named_scope("compact"):
+            first = seg_first_gt(x_all, geom_all, thr, caps_sel, bs_segs)
+    if any_trim:
+        caps = {s: _cap_for(2 * geom_all.seg_ks[s], r1 - r0, geom_all.block)
+                for s, (r0, r1) in enumerate(geom_all.seg_rows)
+                if trim_np[s]}
+        vals, idx, cnts = _seg_buckets(x_all, geom_all, thr,
+                                       max(caps.values()),
+                                       use_pallas=use_pallas,
+                                       interpret=interpret)
 
-    # --- per-slot gathers (plain jnp on the short buckets) --------------
+    # --- per-slot results (trimmed: plain jnp on the short buckets) -----
     results: list[tuple[list[Selected], jax.Array]] = []
     seg0 = 0
     for (x2d, geom, spec, _stats) in parts:
         out: list[Selected] = []
-        for sl, ((prow0, prow1), k, size) in enumerate(
-                zip(geom.seg_rows, geom.seg_ks, geom.seg_sizes)):
+        for sl, (k, size) in enumerate(zip(geom.seg_ks, geom.seg_sizes)):
             s = seg0 + sl
+            if spec.alg != "trimmed":
+                out.append(first[s])
+                continue
             row0, row1 = geom_all.seg_rows[s]
             cap = caps[s]
-            cap_sel = caps_sel[s]
-            if spec.alg == "trimmed":
-                si, sv = _gather_topk_from_buckets(
-                    vals[row0:row1, :cap], idx[row0:row1, :cap], k, size,
-                    order_by_magnitude=True)
-                # too few survivors (the ladder bottomed out) or a
-                # dropped one (bucket overflow): the buckets cannot
-                # yield the top-k
-                fallback = jnp.any(cnts[row0:row1] > cap) | (nnz_loop[s] < k)
-                if use_pallas:
-                    # mirror ops.trimmed_topk: exact top-k
-                    def exact(_, sl=sl, k=k, x2d=x2d, geom=geom):
-                        from repro.core.selection import exact_topk
-                        with jax.named_scope("fallback"):
-                            e = exact_topk(_slot_flat(x2d, geom, sl), k)
-                        return e.indices, e.values
-                else:
-                    # mirror selection.trimmed_topk (no buckets at all):
-                    # the full top-k pads with real zero-score indices
-                    # when nnz < k
-                    def exact(_, sl=sl, k=k, t=thr[s], x2d=x2d, geom=geom):
-                        from repro.core.selection import _pad_topk
-                        with jax.named_scope("fallback"):
-                            flat = _slot_flat(x2d, geom, sl)
-                            score = jnp.where(jnp.abs(flat) > t,
-                                              jnp.abs(flat), 0.0)
-                            e = _pad_topk(flat, score, k)
-                        return e.indices, e.values
-
-                si, sv = jax.lax.cond(fallback, exact,
-                                      lambda _, si=si, sv=sv: (si, sv),
-                                      operand=None)
-                out.append(Selected(si, sv, jnp.int32(k)))
-            else:
-                si, sv = _gather_topk_from_buckets(
-                    vals[row0:row1, :cap], idx[row0:row1, :cap], cap_sel,
-                    size, order_by_magnitude=False)
-                overflow = jnp.any(cnts[row0:row1] > cap)
-
-                def exact(_, sl=sl, c=cap_sel, t=thr[s], x2d=x2d, geom=geom):
+            si, sv = _gather_topk_from_buckets(
+                vals[row0:row1, :cap], idx[row0:row1, :cap], k, size,
+                order_by_magnitude=True)
+            # too few survivors (the ladder bottomed out) or a dropped
+            # one (bucket overflow): the buckets cannot yield the top-k
+            fallback = jnp.any(cnts[row0:row1] > cap) | (nnz_loop[s] < k)
+            if use_pallas:
+                # mirror ops.trimmed_topk: exact top-k
+                def exact(_, sl=sl, k=k, x2d=x2d, geom=geom):
+                    from repro.core.selection import exact_topk
                     with jax.named_scope("fallback"):
-                        e = threshold_filter(_slot_flat(x2d, geom, sl), t,
-                                             capacity=c)
+                        e = exact_topk(_slot_flat(x2d, geom, sl), k)
+                    return e.indices, e.values
+            else:
+                # mirror selection.trimmed_topk (no buckets at all): the
+                # full top-k pads with real zero-score indices when
+                # nnz < k
+                def exact(_, sl=sl, k=k, t=thr[s], x2d=x2d, geom=geom):
+                    from repro.core.selection import _pad_topk
+                    with jax.named_scope("fallback"):
+                        flat = _slot_flat(x2d, geom, sl)
+                        score = jnp.where(jnp.abs(flat) > t,
+                                          jnp.abs(flat), 0.0)
+                        e = _pad_topk(flat, score, k)
                     return e.indices, e.values
 
-                si, sv = jax.lax.cond(overflow, exact,
-                                      lambda _, si=si, sv=sv: (si, sv),
-                                      operand=None)
-                out.append(Selected(si, sv,
-                                    jnp.minimum(nnz_full[s], cap_sel),
-                                    nnz_full[s] > cap_sel))
+            si, sv = jax.lax.cond(fallback, exact,
+                                  lambda _, si=si, sv=sv: (si, sv),
+                                  operand=None)
+            out.append(Selected(si, sv, jnp.int32(k)))
         results.append((out, thr[seg0:seg0 + geom.n_seg]))
         seg0 += geom.n_seg
     return results

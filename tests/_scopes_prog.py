@@ -27,7 +27,7 @@ from repro.models.registry import get_model
 from repro.train.trainer import (make_fsdp_dense_step, make_gradient_sync,
                                  make_train_step)
 
-SCOPES = ("fwd_bwd", "sync", *STAGES, "search", "fallback")
+SCOPES = ("fwd_bwd", "sync", *STAGES, "search", "compact")
 
 
 def scopes_in(text: str) -> set[str]:

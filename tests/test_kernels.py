@@ -340,6 +340,64 @@ class TestSegmentedKernels:
             jnp.asarray(v).astype(jnp.bfloat16).astype(jnp.float32)))
 
 
+def _plant(arrs, slot, at, value):
+    a = np.asarray(arrs[slot]).copy()
+    a[at] = value
+    arrs[slot] = jnp.asarray(a)
+
+
+def _packed_row():
+    # 300 survivors in one row of a slot whose old per-row bucket held
+    # 88 (the case that fell back), a few spread over the other slot
+    from repro.core import arena as A
+    _, _, arrs = _arena([100_000, 4096], seed=41)
+    _plant(arrs, 0, slice(7 * 1024 + 3, 7 * 1024 + 303), -9.0)
+    _plant(arrs, 1, [0, 1023, 1024, 4095], 9.0)
+    group = A.build_group(0, "threshold_bsearch", "float32",
+                          [(i, f"l{i}", a.size, a.size // 100,
+                            a.size // 50, 1) for i, a in enumerate(arrs)])
+    assert _bucket_cap(group.geometry, 0) < 300
+    return [(A.gather(group, arrs), group.geometry)], [[6.0, 6.0]]
+
+
+def _bucket_cap(geom, s):
+    """The per-row bucket the bucket compaction gives slot ``s``."""
+    from repro.kernels import segmented as kseg
+    r0, r1 = geom.seg_rows[s]
+    return kseg._cap_for(2 * geom.seg_ks[s], r1 - r0, geom.block)
+
+
+def _one(sizes, seed, thr):
+    x2d, geom, _ = _arena(sizes, seed=seed)
+    return [(x2d, geom)], [thr]
+
+
+def _ragged():
+    # nonzero lanes past each slot's size: never selected
+    x2d, geom, _ = _arena([1023, 1025, 7, 5000], seed=43)
+    x = np.asarray(x2d).reshape(-1).copy()
+    for (r0, r1), size in zip(geom.seg_rows, geom.seg_sizes):
+        x[r0 * 1024 + size:r1 * 1024] = 50.0
+    return [(jnp.asarray(x.reshape(x2d.shape)), geom)], [[2.0] * 4]
+
+
+def _two_arenas():
+    a = _arena([33_001, 500], seed=44)
+    b = _arena([4096, 1023, 2048], seed=45)
+    return ([(a[0], a[1]), (b[0], b[1])],
+            [[2.5, 0.5], [3.0, 100.0, 1.0]])
+
+
+COMPACT_CASES = {
+    "packed_row": _packed_row,
+    "padding": lambda: _one([33_001, 4096], 42, [3.0, 3.0]),
+    "truncation": lambda: _one([33_001, 4096], 42, [0.5, 0.5]),
+    "ragged": _ragged,
+    "empty": lambda: _one([4096, 2048], 46, [100.0, 1.0]),
+    "two_arenas": _two_arenas,
+}
+
+
 class TestSegmentedSelectors:
     """Segmented selectors vs the per-leaf selectors, slot by slot
     (the bitwise contract the arena pipeline rests on)."""
@@ -381,3 +439,28 @@ class TestSegmentedSelectors:
             np.testing.assert_array_equal(sel_list[i].values, want.values)
             assert int(sel_list[i].count) == int(want.count)
             np.testing.assert_array_equal(thr[i], thr_want)
+
+    @pytest.mark.parametrize("case", list(COMPACT_CASES))
+    @pytest.mark.parametrize("use_pallas", [False, True])
+    def test_bsearch_compaction_is_threshold_filter(self, use_pallas, case):
+        """The bsearch compaction at fixed thresholds (threshold reuse,
+        no search) is each slot's ``threshold_filter``, bitwise: indices,
+        values, count and overflow."""
+        from repro.kernels import segmented as kseg
+        arenas, thrs = COMPACT_CASES[case]()
+        parts = [(x2d, geom, kseg.SegmentSpec(
+            alg="bsearch", eps=1e-3, refresh=jnp.zeros(geom.n_seg, bool),
+            cached=jnp.asarray(t, jnp.float32)), None)
+            for (x2d, geom), t in zip(arenas, thrs)]
+        out = kseg.multi_select(parts, use_pallas=use_pallas,
+                                interpret=True)
+        for (x2d, geom), t, (sel_list, thr) in zip(arenas, thrs, out):
+            np.testing.assert_array_equal(thr, np.float32(t))
+            for s, got in enumerate(sel_list):
+                want = sel.threshold_filter(kseg._slot_flat(x2d, geom, s),
+                                            jnp.float32(t[s]),
+                                            capacity=2 * geom.seg_ks[s])
+                for g, w in zip(got, want):
+                    assert g.dtype == w.dtype and g.shape == w.shape
+                    np.testing.assert_array_equal(g, w)
+

@@ -1,10 +1,12 @@
 """The names the training step carries into its compiled program and its
 profiler trace: device scopes ``fwd_bwd``, ``sync``, the six
-``StageTimer`` stages, ``search`` and ``fallback`` in every instruction's
-``op_name``, and the host spans of ``Trainer.run``.
+``StageTimer`` stages, ``search``, ``compact`` (bsearch slots) and
+``fallback`` (trimmed slots) in every instruction's ``op_name``, and the
+host spans of ``Trainer.run``.
 
 Meshes need forced host devices before jax initializes, so those cases
 run tests/_scopes_prog.py in a subprocess (``run_prog``)."""
+import dataclasses
 import os
 import re
 
@@ -29,20 +31,38 @@ def scopes_in(text: str) -> set[str]:
             for part in name.split("/")[:-1]}
 
 
-def test_smoke_step_names_its_stages():
+def smoke_step_text(tc: TrainConfig) -> str:
     model = get_model(get_config("paper-lstm", smoke=True))
     params = jax.eval_shape(model.init_params)
-    state = jax.eval_shape(make_gradient_sync(TC, None).init, params)
-    step = make_train_step(model, None, None, TC, donate=False)
-    text = step.lower(params, state, model.train_inputs(2, 16),
-                      jax.ShapeDtypeStruct((), jnp.float32)).compile().as_text()
+    state = jax.eval_shape(make_gradient_sync(tc, None).init, params)
+    step = make_train_step(model, None, None, tc, donate=False)
+    return step.lower(params, state, model.train_inputs(2, 16),
+                      jax.ShapeDtypeStruct((), jnp.float32)
+                      ).compile().as_text()
+
+
+def test_smoke_step_names_its_stages():
+    text = smoke_step_text(TC)
     found = scopes_in(text)
-    wanted = {"fwd_bwd", "sync", "search", "fallback"} | (
+    wanted = {"fwd_bwd", "sync", "search", "compact"} | (
         set(STAGES) - {"transfer"})
     assert wanted <= found, wanted - found
-    # the bisection's survivor count sits inside the search loop, the
-    # exact fallback inside a slot's conditional branch
+    # the bisection's survivor count sits inside the search loop; the
+    # bsearch slots share one compaction and have no per-slot branch
     assert re.search(r'op_name="[^"]*/select/while/body/search/', text)
+    assert re.search(r'op_name="[^"]*/select/compact/', text)
+    assert "fallback" not in found
+    assert not [line for line in text.splitlines()
+                if " conditional(" in line and "/select/" in line]
+
+
+def test_trimmed_step_names_its_fallback():
+    """Trimmed top-k slots keep their buckets and the per-slot exact
+    fallback, inside a conditional branch under ``select``."""
+    text = smoke_step_text(dataclasses.replace(
+        TC, optimizer="momentum+clip(trimmed_topk)"))
+    found = scopes_in(text)
+    assert "fallback" in found and "compact" not in found
     assert re.search(r'op_name="[^"]*/select/cond/[^"]*/fallback/', text)
 
 

@@ -21,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import TrainConfig, get_config
 from repro.core import arena
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.kernels import segmented as kseg
 from repro.models.registry import get_model
 from repro.train.trainer import make_gradient_sync, make_train_step
@@ -121,6 +121,33 @@ def test_segmented_kernel_compiles(one_chip, kernel, arena_name):
                              sharding=one_chip)
     t = jax.ShapeDtypeStruct((geom.n_seg,), jnp.float32, sharding=one_chip)
     _assert_kernel(_compile(_seg_call(kernel, geom, stride_b), x, t))
+
+
+def test_bsearch_compaction_fits_in_bucket_temporaries(one_chip):
+    """The paper-lstm arena's bsearch ``multi_select`` (jnp backend,
+    statistics given, as the fused accumulate hands them over) compiles
+    for one chip with no more temporary bytes than the bucket compaction
+    it replaced takes alone at the same width: no scatter, no per-slot
+    branch, and ``peak_hbm_gb`` cannot grow unseen."""
+    geom = _geometry(_paper_lstm_leaf_sizes())
+    x = jax.ShapeDtypeStruct((geom.nblocks, geom.block), jnp.float32,
+                             sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((geom.n_seg,), jnp.float32,
+                               sharding=one_chip)
+    spec = kseg.SegmentSpec(alg="bsearch", eps=1e-3)
+    select = _compile(lambda x, m, mx: kseg.multi_select(
+        [(x, geom, spec, (m, mx))], use_pallas=False), x, vec, vec)
+    cap = max(kseg._cap_for(2 * k, r1 - r0, geom.block)
+              for k, (r0, r1) in zip(geom.seg_ks, geom.seg_rows))
+    buckets = _compile(lambda x, t: ref.seg_compact_gt(
+        x, geom.block_seg, geom.block_base, geom.block_size, t, cap), x, vec)
+    lines = select.as_text().splitlines()
+    # the only scatters left are the search's per-segment counts
+    assert all(f"[{geom.n_seg}]" in line.split(" scatter(")[0]
+               for line in lines if " scatter(" in line)
+    assert not [line for line in lines if " conditional(" in line]
+    assert (select.memory_analysis().temp_size_in_bytes
+            <= buckets.memory_analysis().temp_size_in_bytes)
 
 
 LEAF_ROWS, LEAF_BLOCK = 15000, 1024     # the paper-lstm embedding leaf
