@@ -2,7 +2,13 @@
 
 24L, d_model 2048, 16 heads (GQA kv=8, head_dim 128), d_ff 8192,
 vocab 92544. Llama-style: RoPE 1e6, SiLU gated MLP, untied embeddings,
-full attention (no window) -> long_500k decode is skipped (DESIGN.md).
+full attention (no window), so long_500k decode is skipped.
+
+The on-chip benchmark trains it in the cell ``internlm2-4l-4k-rgc``
+(``benchmarks/chip/configs/internlm2-1.8b-4l.json``): every width as
+here, 4 of the 24 layers (one stage of six) and a quarter of the
+vocabulary (23,136 rows, one chip of four in a vocabulary split), bf16
+weights with float32 residual and velocity, 4 x 4096 tokens a step.
 """
 from __future__ import annotations
 

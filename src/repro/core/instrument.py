@@ -24,13 +24,17 @@ Both implementations run each stage's thunk under
 step: every instruction a stage emits carries it in its ``op_name``
 (``jit(step)/sync/select/...``), and so does each op event of a profiler
 trace of that step. The trainer adds ``fwd_bwd`` (the model's loss and
-gradients) and ``sync`` (``GradientSync.update``) around them, and
-``kernels.segmented.multi_select`` adds ``search`` (one survivor count of
-the bisection loop), ``compact`` (the one exact compaction of every
-bsearch slot) and ``fallback`` (a trimmed slot's exact top-k after its
-buckets overflow) inside ``select``. Every schedule and transport names
-its stages through this hook. To read the stages' device time, trace a
-few steps on the chip and reduce the trace by scope::
+gradients) and ``sync`` (``GradientSync.update``) around them; inside
+``fwd_bwd`` the models add ``attn`` (``models.attention.attention_block``:
+QKV projection, RoPE, attention, output projection), ``mlp``
+(``models.common.mlp``, the gated MLP) and ``loss`` (each chunk of the
+chunked cross-entropy); ``kernels.segmented.multi_select`` adds ``search``
+(one survivor count of the bisection loop), ``compact`` (the one exact
+compaction of every bsearch slot) and ``fallback`` (a trimmed slot's
+exact top-k after its buckets overflow) inside ``select``. Every
+schedule and transport names its stages through this hook. To read the
+stages' device time, trace a few steps on the chip and reduce the trace
+by scope::
 
     with jax.profiler.trace(trace_dir):
         state = trainer.run(state, batches, 5)

@@ -248,39 +248,43 @@ def attention_block(
     differentiable: bool = True,
 ) -> AttnOut:
     """Unified self-attention: train (no cache), prefill (fill_cache=True),
-    decode (cache + decode_pos)."""
-    b, s, _ = x.shape
-    q, k, v = _project_qkv(cfg, p, x, positions, theta)
+    decode (cache + decode_pos). Runs under the ``attn`` scope: the QKV
+    projection, RoPE, the attention itself and the output projection."""
+    with jax.named_scope("attn"):
+        b, s, _ = x.shape
+        q, k, v = _project_qkv(cfg, p, x, positions, theta)
 
-    if cache is not None and decode_pos is not None:      # decode
-        cache = cache_insert(cache, k, v, decode_pos)
-        out = decode_attention(q, cache, decode_pos, window=window,
-                               softcap=softcap)
-    else:                                                 # train / prefill
-        out = flash_attention(
-            q, k, v, causal=causal, window=window, prefix_len=prefix_len,
-            q_chunk=cfg.attn_q_chunk, kv_chunk=cfg.attn_kv_chunk,
-            softcap=softcap, differentiable=differentiable)
-        if fill_cache and cache is not None:
-            c = cache.k.shape[1]
-            if cache.ring:
-                # keep the last `c` positions (prefill longer than window)
-                start = jnp.maximum(0, s - c)
-                k_tail = jax.lax.dynamic_slice_in_dim(k, start, min(c, s), 1)
-                v_tail = jax.lax.dynamic_slice_in_dim(v, start, min(c, s), 1)
-                # ring layout: slot = pos % c; for pos = start..start+c-1
-                slots = jnp.remainder(start + jnp.arange(min(c, s)), c)
-                kc_ = cache.k.at[:, slots].set(k_tail.astype(cache.k.dtype))
-                vc_ = cache.v.at[:, slots].set(v_tail.astype(cache.v.dtype))
-                cache = KVCache(kc_, vc_, True)
-            else:
-                kc_ = jax.lax.dynamic_update_slice_in_dim(
-                    cache.k, k.astype(cache.k.dtype), 0, axis=1)
-                vc_ = jax.lax.dynamic_update_slice_in_dim(
-                    cache.v, v.astype(cache.v.dtype), 0, axis=1)
-                cache = KVCache(kc_, vc_, False)
+        if cache is not None and decode_pos is not None:      # decode
+            cache = cache_insert(cache, k, v, decode_pos)
+            out = decode_attention(q, cache, decode_pos, window=window,
+                                   softcap=softcap)
+        else:                                                 # train / prefill
+            out = flash_attention(
+                q, k, v, causal=causal, window=window, prefix_len=prefix_len,
+                q_chunk=cfg.attn_q_chunk, kv_chunk=cfg.attn_kv_chunk,
+                softcap=softcap, differentiable=differentiable)
+            if fill_cache and cache is not None:
+                c = cache.k.shape[1]
+                if cache.ring:
+                    # keep the last `c` positions (prefill longer than window)
+                    start = jnp.maximum(0, s - c)
+                    n = min(c, s)
+                    k_tail = jax.lax.dynamic_slice_in_dim(k, start, n, 1)
+                    v_tail = jax.lax.dynamic_slice_in_dim(v, start, n, 1)
+                    # ring layout: slot = pos % c; for pos = start..start+c-1
+                    slots = jnp.remainder(start + jnp.arange(n), c)
+                    kc_ = cache.k.at[:, slots].set(
+                        k_tail.astype(cache.k.dtype))
+                    vc_ = cache.v.at[:, slots].set(
+                        v_tail.astype(cache.v.dtype))
+                    cache = KVCache(kc_, vc_, True)
+                else:
+                    kc_ = jax.lax.dynamic_update_slice_in_dim(
+                        cache.k, k.astype(cache.k.dtype), 0, axis=1)
+                    vc_ = jax.lax.dynamic_update_slice_in_dim(
+                        cache.v, v.astype(cache.v.dtype), 0, axis=1)
+                    cache = KVCache(kc_, vc_, False)
 
-    b_, s_, hq, hd = out.shape if out.ndim == 4 else (b, s, cfg.num_heads, cfg.head_dim)
-    o = out.reshape(b, -1, cfg.num_heads * cfg.head_dim)
-    o = shard(o, None, None, "model")
-    return AttnOut(shard(o @ p["wo"], None, None, None), cache)
+        o = out.reshape(b, -1, cfg.num_heads * cfg.head_dim)
+        o = shard(o, None, None, "model")
+        return AttnOut(shard(o @ p["wo"], None, None, None), cache)

@@ -226,10 +226,12 @@ def mlp_defs(cfg: ModelConfig) -> dict:
 
 
 def mlp(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
-    g = shard(x @ p["w_gate"], None, None, "model")
-    u = shard(x @ p["w_up"], None, None, "model")
-    h = act_fn(cfg.act)(g) * u
-    return shard(h @ p["w_down"], None, None, None)
+    """Gated MLP, under the ``mlp`` scope."""
+    with jax.named_scope("mlp"):
+        g = shard(x @ p["w_gate"], None, None, "model")
+        u = shard(x @ p["w_up"], None, None, "model")
+        h = act_fn(cfg.act)(g) * u
+        return shard(h @ p["w_down"], None, None, None)
 
 
 def embed_defs(cfg: ModelConfig) -> dict:
@@ -260,7 +262,10 @@ def chunked_ce_loss(cfg: ModelConfig, embed_p: dict, hidden: jax.Array,
 
     hidden: [B,S,D]; labels: [B,S] i32 (targets aligned with hidden);
     mask: [B,S] f32 weights (None = all ones). Scans over sequence chunks;
-    each chunk's logits are vocab-sharded and remat'd.
+    each chunk's logits are vocab-sharded and remat'd. Each chunk's logits
+    and cross-entropy run under the ``loss`` scope, inside the scanned body
+    so that the name stays bare (a scope outside it would read
+    ``jvp(loss)`` / ``transpose(jvp(loss))`` under autodiff).
     """
     b, s, d = hidden.shape
     chunk = min(cfg.loss_chunk, s)
@@ -279,12 +284,14 @@ def chunked_ce_loss(cfg: ModelConfig, embed_p: dict, hidden: jax.Array,
 
     @jax.checkpoint
     def body(carry, xs):
-        h_c, l_c, m_c = xs
-        logits = lm_logits(cfg, embed_p, h_c).astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, l_c[..., None], axis=-1)[..., 0]
-        nll = (lse - gold) * m_c
-        return (carry[0] + jnp.sum(nll), carry[1] + jnp.sum(m_c)), None
+        with jax.named_scope("loss"):
+            h_c, l_c, m_c = xs
+            logits = lm_logits(cfg, embed_p, h_c).astype(jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, l_c[..., None],
+                                       axis=-1)[..., 0]
+            nll = (lse - gold) * m_c
+            return (carry[0] + jnp.sum(nll), carry[1] + jnp.sum(m_c)), None
 
     (tot, cnt), _ = jax.lax.scan(body, (jnp.float32(0.0), jnp.float32(0.0)),
                                  (hs, ls, ms))

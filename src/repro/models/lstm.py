@@ -94,12 +94,14 @@ def loss(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
     ms = mask.reshape(b, n, chunk).swapaxes(0, 1)
 
     def body(carry, xs):
-        h_c, l_c, m_c = xs
-        logits = _logits(cfg, params, h_c).astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, l_c[..., None], axis=-1)[..., 0]
-        return (carry[0] + jnp.sum((lse - gold) * m_c),
-                carry[1] + jnp.sum(m_c)), None
+        with jax.named_scope("loss"):      # as common.chunked_ce_loss
+            h_c, l_c, m_c = xs
+            logits = _logits(cfg, params, h_c).astype(jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, l_c[..., None],
+                                       axis=-1)[..., 0]
+            return (carry[0] + jnp.sum((lse - gold) * m_c),
+                    carry[1] + jnp.sum(m_c)), None
 
     (tot, cnt), _ = jax.lax.scan(
         jax.checkpoint(body), (jnp.float32(0.0), jnp.float32(0.0)),

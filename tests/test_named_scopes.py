@@ -1,11 +1,13 @@
 """The names the training step carries into its compiled program and its
 profiler trace: device scopes ``fwd_bwd``, ``sync``, the six
 ``StageTimer`` stages, ``search``, ``compact`` (bsearch slots) and
-``fallback`` (trimmed slots) in every instruction's ``op_name``, and the
+``fallback`` (trimmed slots), and the model's ``attn``, ``mlp`` and
+``loss`` inside ``fwd_bwd``, in every instruction's ``op_name``, and the
 host spans of ``Trainer.run``.
 
 Meshes need forced host devices before jax initializes, so those cases
 run tests/_scopes_prog.py in a subprocess (``run_prog``)."""
+import contextlib
 import dataclasses
 import os
 import re
@@ -31,8 +33,8 @@ def scopes_in(text: str) -> set[str]:
             for part in name.split("/")[:-1]}
 
 
-def smoke_step_text(tc: TrainConfig) -> str:
-    model = get_model(get_config("paper-lstm", smoke=True))
+def smoke_step_text(tc: TrainConfig, arch: str = "paper-lstm") -> str:
+    model = get_model(get_config(arch, smoke=True))
     params = jax.eval_shape(model.init_params)
     state = jax.eval_shape(make_gradient_sync(tc, None).init, params)
     step = make_train_step(model, None, None, tc, donate=False)
@@ -64,6 +66,43 @@ def test_trimmed_step_names_its_fallback():
     found = scopes_in(text)
     assert "fallback" in found and "compact" not in found
     assert re.search(r'op_name="[^"]*/select/cond/[^"]*/fallback/', text)
+
+
+MODEL_SCOPES = {"internlm2-1.8b": ("attn", "mlp", "loss"),
+                "paper-lstm": ("loss",)}
+
+
+@pytest.mark.parametrize("arch", sorted(MODEL_SCOPES))
+def test_smoke_step_names_its_model_parts(arch):
+    """The model's parts sit inside ``fwd_bwd`` under their bare names,
+    in the forward and in the rematerialised backward of the scanned
+    layers and loss chunks."""
+    text = smoke_step_text(TC, arch)
+    for scope in MODEL_SCOPES[arch]:
+        for way in (r"jvp\(\)", r"transpose\(jvp\(\)\)"):
+            assert re.search(rf'op_name="jit\(step\)/fwd_bwd/{way}/'
+                             rf'[^"]*/{scope}/', text), (scope, way)
+    assert {"attn", "mlp", "loss"} & scopes_in(text) == set(
+        MODEL_SCOPES[arch])
+
+
+def _instructions(text: str) -> list[str]:
+    return [re.sub(r", metadata=\{[^}]*\}", "", line)
+            for line in text.splitlines() if " = " in line]
+
+
+@pytest.mark.parametrize("arch", sorted(MODEL_SCOPES))
+def test_model_scopes_are_metadata_only(arch, monkeypatch):
+    """Without the model's scopes the compiled step has the same
+    instructions; only their metadata differs."""
+    scoped = smoke_step_text(TC, arch)
+    real = jax.named_scope
+    monkeypatch.setattr(
+        jax, "named_scope", lambda name: contextlib.nullcontext()
+        if name in MODEL_SCOPES[arch] else real(name))
+    bare = smoke_step_text(TC, arch)
+    assert scoped != bare
+    assert _instructions(scoped) == _instructions(bare)
 
 
 @pytest.mark.parametrize("case", ["data", "data_model", "fsdp"])
